@@ -88,4 +88,7 @@ def main_legacy() -> None:  # kept for the original scaffold entry point
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(main())

@@ -82,6 +82,11 @@ def aggregate_rows(rows: List[dict]) -> Dict[str, Dict[str, float]]:
     return out
 
 
+def _uses_device(spec_dict: dict) -> bool:
+    """Whether a job's policy scores on the device (``backend: "jax"``)."""
+    return spec_dict["policy"]["params"].get("backend") == "jax"
+
+
 def _run_job(job) -> dict:
     spec_dict, seed, until = job
     return run_one(RunSpec.from_dict(spec_dict), seed, until=until)
@@ -249,6 +254,10 @@ def run_experiment(exp: ExperimentSpec, processes: Optional[int] = None,
 
     if processes is None:
         processes = min(os.cpu_count() or 1, max(len(jobs), 1))
+    if any(_uses_device(job[0]) for job in jobs):
+        # a device belongs to one process: forked workers would all reach
+        # for it, so a sweep with a device-backed job runs in this process
+        processes = 1
     if processes > 1 and len(jobs) > 1:
         # prefer fork so registry entries added at runtime (e.g. a custom
         # policy registered in the caller's __main__) survive into workers;

@@ -29,10 +29,11 @@ from typing import Sequence, Tuple
 import numpy as np
 
 from .hlem import (
+    certified_pick,
     hlem_pick_candidates_np,
     hlem_pick_np,
     hlem_scores_batch_np,
-    hlem_select_jax,
+    hlem_scores_tol_jax,
 )
 from .hosts import HostPool
 from ..obs.tracer import NULL_TRACER
@@ -233,6 +234,10 @@ class HlemVmp(AllocationPolicy):
         self.threshold = threshold
         assert backend in ("numpy", "jax")
         self.backend = backend
+        #: device-scored picks, and those a float32 near-tie sent back to
+        #: the exact host pick (``backend="jax"`` only)
+        self.device_picks = 0
+        self.device_fallbacks = 0
 
     # -- phase 1 ------------------------------------------------------------
     def _rsdiff_ok(self, vm: Vm, pool: HostPool) -> np.ndarray:
@@ -249,13 +254,24 @@ class HlemVmp(AllocationPolicy):
     def _score_pick(self, mask: np.ndarray, vm: Vm, pool: HostPool) -> int:
         if not mask.any():
             return -1
-        free = pool.free()
-        spot_frac = pool.spot_frac_view()
         alpha = self._alpha_for(vm)
         if self.backend == "jax":
-            hid = int(hlem_select_jax(free, mask, spot_frac, np.float32(alpha)))
-            return hid
-        return hlem_pick_np(free, mask, spot_frac, alpha)
+            # score the pool's whole storage (rows past n masked off): its
+            # row count only changes when storage doubles, so the jitted
+            # scorer compiles a few times per run, not once per host count
+            free, spot_frac = pool.storage_views()
+            padded = np.zeros(free.shape[0], dtype=bool)
+            padded[: mask.size] = mask
+            scores, tol = hlem_scores_tol_jax(free, padded, spot_frac,
+                                              np.float32(alpha))
+            self.device_picks += 1
+            hid = certified_pick(np.asarray(scores), float(tol), free,
+                                 spot_frac)
+            if hid is not None:
+                return hid
+            # a near-tie float32 cannot order: the exact pick decides
+            self.device_fallbacks += 1
+        return hlem_pick_np(pool.free(), mask, pool.spot_frac_view(), alpha)
 
     def _pick_direct(self, mask, vm, pool):
         # primary candidate list: feasible AND RsDiff above threshold;

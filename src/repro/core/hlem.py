@@ -3,9 +3,11 @@
 Three implementations of the same math:
 
 * ``hlem_scores_np``  — pure-numpy oracle (readable, used as test reference),
-* ``hlem_scores_jax`` — vectorized/jitted JAX (production path on accelerators),
-* ``repro.kernels.hlem_score`` — Pallas TPU kernel (tiled over hosts), validated
-  against the numpy oracle in interpret mode.
+* ``hlem_scores_jax`` — vectorized/jitted JAX in float32 (the device path;
+  :func:`certified_pick` keeps its decisions those of the float64 oracle),
+* ``repro.kernels.hlem_score`` — Pallas TPU kernel (tiled over hosts), checked
+  against the numpy oracle in interpret mode and, on a TPU, by
+  ``chip_smoke.py``.
 
 All take a *masked* formulation: every host is scored, infeasible hosts carry
 ``mask=False`` and receive ``-inf`` so downstream argmax ignores them.  This is
@@ -281,14 +283,16 @@ def hlem_scores_batch_np(
 # ---------------------------------------------------------------------------
 # JAX (jitted, mask-based — fixed shapes, no data-dependent control flow)
 # ---------------------------------------------------------------------------
-@functools.partial(jax.jit, static_argnames=())
-def hlem_scores_jax(
-    free: jax.Array,           # (n, D) float32/float64
-    mask: jax.Array,           # (n,) bool
-    spot_frac: jax.Array,      # (n, D)
-    alpha: jax.Array,          # scalar
-) -> jax.Array:
-    """Identical math to ``hlem_scores_np``, jit-compiled."""
+#: scale of the float32 error of a score *difference* (scores are O(1)).
+#: :func:`hlem_scores_tol_jax` multiplies it by ``1 + 1/sum(g)``, the
+#: amplification of entropies near 1 (Eq. 7), and adds the rounding of the
+#: float32 inputs over each dimension's span.  Measured against it, with a
+#: wide margin: errors up to 1.4e-5 (at sum(g) ~ 0.06) and error x sum(g)
+#: up to 8.5e-7 over sampled trace picks on a CPU.
+DEVICE_TIE_TOL = 1e-5
+
+
+def _scores_and_tol(free, mask, spot_frac, alpha):
     free = free.astype(jnp.float32)
     maskf = mask.astype(jnp.float32)[:, None]          # (n,1)
     m = jnp.sum(maskf)                                 # candidate count
@@ -315,10 +319,56 @@ def hlem_scores_jax(
     d = free.shape[1]
     w = jnp.where(gsum > _EPS, g / jnp.where(gsum > _EPS, gsum, 1.0), 1.0 / d)
 
-    hs = c_std @ w
-    sl = spot_frac.astype(jnp.float32) @ w
+    # elementwise multiply + reduce, not ``@``: a TPU matmul of float32
+    # operands rounds them to bfloat16 at default precision
+    hs = jnp.sum(c_std * w, axis=1)
+    sl = jnp.sum(spot_frac.astype(jnp.float32) * w, axis=1)
     hs = hs * (1.0 + alpha * sl)
-    return jnp.where(mask, hs, -big)
+
+    # float32 rounds an input by up to 2^-24 of its magnitude, and the
+    # standardization divides that by the dimension's span (Eq. 3)
+    mag = jnp.max(jnp.where(mask[:, None], jnp.abs(free), 0.0), axis=0)
+    rounding = jnp.sum(jnp.where(degen, 0.0,
+                                 w * mag / jnp.where(degen, 1.0, span)))
+    tol = (DEVICE_TIE_TOL * (1.0 + 1.0 / jnp.maximum(gsum, 1e-30))
+           + 2.0 ** -20 * rounding)
+    return jnp.where(mask, hs, -big), tol
+
+
+@jax.jit
+def hlem_scores_jax(
+    free: jax.Array,           # (n, D) float32/float64
+    mask: jax.Array,           # (n,) bool
+    spot_frac: jax.Array,      # (n, D)
+    alpha: jax.Array,          # scalar
+) -> jax.Array:
+    """Identical math to ``hlem_scores_np``, jit-compiled."""
+    return _scores_and_tol(free, mask, spot_frac, alpha)[0]
+
+
+@jax.jit
+def hlem_scores_tol_jax(free, mask, spot_frac, alpha):
+    """(scores, tol): :func:`hlem_scores_jax` and a bound on the float32
+    error of any difference of two of its scores."""
+    return _scores_and_tol(free, mask, spot_frac, alpha)
+
+
+def certified_pick(scores: np.ndarray, tol: float, free: np.ndarray,
+                   spot_frac: np.ndarray) -> int | None:
+    """The float64 oracle's argmax read off float32 ``scores``, or None
+    when float32 cannot decide it.
+
+    Every host within ``tol`` (:func:`hlem_scores_tol_jax`) of the float32
+    maximum could be the oracle's pick.  When all of them carry the same
+    (free, spot_frac) row in float64, they score identically in both
+    precisions, and the oracle picks the first of them; otherwise the
+    caller must score exactly."""
+    near = np.flatnonzero(scores >= scores.max() - tol)
+    first = near[0]
+    if near.size == 1 or ((free[near] == free[first]).all()
+                          and (spot_frac[near] == spot_frac[first]).all()):
+        return int(first)
+    return None
 
 
 @jax.jit

@@ -255,6 +255,12 @@ class HostPool:
         Returns a cached read-only-by-convention view; do not mutate."""
         return self._free[: self.n]
 
+    def storage_views(self) -> Tuple[np.ndarray, np.ndarray]:
+        """(free, spot_frac) over every allocated row, not just the first
+        ``n``: rows past ``n`` are zero.  Their row count changes only when
+        the storage grows (doubling)."""
+        return self._free, self._spot_frac
+
     def spot_frac_view(self) -> np.ndarray:
         """(n_hosts, 4) spot_used / total (cached)."""
         return self._spot_frac[: self.n]

@@ -12,9 +12,9 @@ TPU's sequential-grid guarantee to carry scratch accumulators across stages:
   stage 2: Σ p·ln p entropy partials                   (Eq. 5)
   stage 3: weights w_d (Eqs. 6-8) + scores HS/AHS      (Eqs. 9-11), written out
 
-Grid = (4 stages, n_host_blocks); scratch persists across the entire grid, so
-no HBM round-trips between stages beyond the single streaming of host data per
-stage (4 × n × D × 4 B total traffic).
+Grid = (B VMs, 4 stages, n_host_blocks); scratch persists across each VM's
+stages, so no HBM round-trips between stages beyond the single streaming of
+host data per stage (4 × n × D × 4 B traffic per VM).
 """
 from __future__ import annotations
 
@@ -32,14 +32,13 @@ DEFAULT_BLOCK = 512
 
 
 def _kernel(alpha_ref, free_ref, spot_ref, mask_ref, out_ref,
-            lo_ref, hi_ref, col_ref, plp_ref, m_ref, *, batched=False):
-    # batched variant: grid (B, 4, nblk) — same 4-stage pipeline per batch
-    # element; scratch accumulators are re-initialized at (stage 0, block 0)
-    # of every element thanks to the TPU's sequential-grid guarantee.
-    sdim = 1 if batched else 0
-    stage = pl.program_id(sdim)
-    jblk = pl.program_id(sdim + 1)
-    nblk = pl.num_programs(sdim + 1)
+            lo_ref, hi_ref, col_ref, plp_ref, m_ref):
+    # grid (B, 4, nblk): the same 4-stage pipeline per batch element; scratch
+    # accumulators are re-initialized at (stage 0, block 0) of every element
+    # thanks to the TPU's sequential-grid guarantee.
+    elem = pl.program_id(0)
+    stage = pl.program_id(1)
+    jblk = pl.program_id(2)
 
     free = free_ref[...]          # (SUB, BN) — rows 0..3 are resource dims
     spot = spot_ref[...]          # (SUB, BN)
@@ -94,11 +93,11 @@ def _kernel(alpha_ref, free_ref, spot_ref, mask_ref, out_ref,
         m = m_ref[0, 0]
         k = jnp.where(m > 1.0, 1.0 / jnp.log(jnp.maximum(m, 2.0)), 0.0)
         e = -k * plp_ref[...]                     # (SUB, 1)
-        d_real = 4.0
+        d_real = 4
         # only rows 0..3 are real dims; padded rows carry col==0 & plp==0 ->
-        # e==0, g==1 — mask them out of the weight normalization.
-        row = jax.lax.broadcasted_iota(jnp.float32, e.shape, 0)
-        real = row < d_real
+        # e==0, g==1 — mask them out of the weight normalization.  Mosaic
+        # lowers integer iotas only.
+        real = jax.lax.broadcasted_iota(jnp.int32, e.shape, 0) < d_real
         g = jnp.where(real, 1.0 - e, 0.0)
         gsum = jnp.sum(g)
         w = jnp.where(gsum > _EPS, g / jnp.where(gsum > _EPS, gsum, 1.0),
@@ -106,7 +105,7 @@ def _kernel(alpha_ref, free_ref, spot_ref, mask_ref, out_ref,
         c = _standardize()
         hs = jnp.sum(c * w, axis=0, keepdims=True)          # (1, BN)
         sl = jnp.sum(spot * w, axis=0, keepdims=True)
-        hs = hs * (1.0 + alpha_ref[0, 0] * sl)
+        hs = hs * (1.0 + alpha_ref[elem] * sl)
         out_ref[...] = jnp.where(maskb, hs, -_BIG)
 
 
@@ -117,45 +116,12 @@ def hlem_score_pallas(free: jax.Array, mask: jax.Array, spot_frac: jax.Array,
     """Drop-in replacement for ``repro.core.hlem.hlem_scores_jax``.
 
     free (n, D) float, mask (n,) bool, spot_frac (n, D), alpha scalar.
-    Returns (n,) float32 scores with -3.4e38 at masked hosts.
+    Returns (n,) float32 scores with -3.4e38 at masked hosts.  The B=1 case
+    of :func:`hlem_score_pallas_batch`.
     """
-    n, d = free.shape
-    assert d <= SUB, f"at most {SUB} resource dims supported, got {d}"
-    n_pad = max(pl.cdiv(n, block), 1) * block
-
-    def to_tiles(x):  # (n, D) -> (SUB, n_pad), host axis on lanes
-        x = jnp.asarray(x, jnp.float32)
-        x = jnp.pad(x, ((0, n_pad - n), (0, SUB - d)))
-        return x.T
-
-    free_t = to_tiles(free)
-    spot_t = to_tiles(spot_frac)
-    mask_t = jnp.pad(mask.astype(jnp.float32), (0, n_pad - n))[None, :]
-    alpha_arr = jnp.full((1, 1), alpha, jnp.float32)
-
-    nblk = n_pad // block
-    out = pl.pallas_call(
-        _kernel,
-        grid=(4, nblk),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda s, j: (0, 0)),
-            pl.BlockSpec((SUB, block), lambda s, j: (0, j)),
-            pl.BlockSpec((SUB, block), lambda s, j: (0, j)),
-            pl.BlockSpec((1, block), lambda s, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda s, j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
-        scratch_shapes=[
-            # lo, hi, col, plogp accumulators (SUB,1) + candidate count (1,1)
-            pltpu.VMEM((SUB, 1), jnp.float32),
-            pltpu.VMEM((SUB, 1), jnp.float32),
-            pltpu.VMEM((SUB, 1), jnp.float32),
-            pltpu.VMEM((SUB, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(alpha_arr, free_t, spot_t, mask_t)
-    return out[0, :n]
+    alphas = jnp.reshape(jnp.asarray(alpha, jnp.float32), (1,))
+    return hlem_score_pallas_batch(free, mask[None], spot_frac, alphas,
+                                   block=block, interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -171,10 +137,12 @@ def hlem_score_pallas_batch(
     spot_frac (n, D), alphas (B,) per-VM adjustment.  Returns (B, n) float32
     scores with -3.4e38 at masked hosts.
 
-    Grid = (B, 4 stages, n_host_blocks): the batch axis is the new leading
-    grid dimension over the existing 4-stage reduction pipeline; host data is
-    streamed once per (element, stage) while each element's masks/outputs tile
-    its own row of the (B, n_pad) layout.
+    Grid = (B, 4 stages, n_host_blocks): the batch axis is the leading grid
+    dimension over the 4-stage reduction pipeline; host data is streamed
+    once per (element, stage).  Masks and outputs are laid out (B, 1, n_pad)
+    so each element's (1, block) tile spans the array's own sublane dim (the
+    TPU tiling rule: the last two block dims divide (8, 128) or equal the
+    array's); the B alphas sit whole in SMEM, indexed by the element id.
     """
     n, d = free.shape
     b = masks.shape[0]
@@ -188,21 +156,22 @@ def hlem_score_pallas_batch(
 
     free_t = to_tiles(free)
     spot_t = to_tiles(spot_frac)
-    masks_t = jnp.pad(masks.astype(jnp.float32), ((0, 0), (0, n_pad - n)))
-    alphas_arr = jnp.asarray(alphas, jnp.float32).reshape(b, 1)
+    masks_t = jnp.pad(masks.astype(jnp.float32),
+                      ((0, 0), (0, n_pad - n)))[:, None, :]
+    alphas_arr = jnp.asarray(alphas, jnp.float32).reshape(b)
 
     nblk = n_pad // block
     out = pl.pallas_call(
-        functools.partial(_kernel, batched=True),
+        _kernel,
         grid=(b, 4, nblk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda bb, s, j: (bb, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((SUB, block), lambda bb, s, j: (0, j)),
             pl.BlockSpec((SUB, block), lambda bb, s, j: (0, j)),
-            pl.BlockSpec((1, block), lambda bb, s, j: (bb, j)),
+            pl.BlockSpec((None, 1, block), lambda bb, s, j: (bb, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, block), lambda bb, s, j: (bb, j)),
-        out_shape=jax.ShapeDtypeStruct((b, n_pad), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, block), lambda bb, s, j: (bb, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, n_pad), jnp.float32),
         scratch_shapes=[
             # lo, hi, col, plogp accumulators (SUB,1) + candidate count (1,1)
             pltpu.VMEM((SUB, 1), jnp.float32),
@@ -213,4 +182,4 @@ def hlem_score_pallas_batch(
         ],
         interpret=interpret,
     )(alphas_arr, free_t, spot_t, masks_t)
-    return out[:, :n]
+    return out[:, 0, :n]

@@ -374,26 +374,34 @@ def simulate_price_paths(family, state: MarketState, utils, shocks,
             "jax backend needs an array-native family (adapter-wrapped "
             "legacy processes only support backend='numpy')")
     import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
-    with enable_x64():
-        def _step(carry, xs):
-            u, z = xs
-            carry, p = family.step(carry, u, z, xp=jnp)
-            return carry, p
-
-        # scan carries must keep a fixed shape: pre-broadcast every state
-        # leaf to the per-tick shock shape (no-op for single-path runs,
-        # (n_paths, n_pools) for Monte-Carlo fans)
-        state64 = {k: jnp.broadcast_to(jnp.asarray(v, dtype=jnp.float64),
-                                       shocks.shape[1:])
-                   for k, v in state.items()}
-        final, prices = jax.lax.scan(
-            _step, state64, (jnp.asarray(utils, dtype=jnp.float64),
-                             jnp.asarray(shocks, dtype=jnp.float64)))
+    with jax.enable_x64(True):
+        final, prices = price_scan(family, state, utils, shocks)
         return (np.asarray(prices, dtype=np.float64),
                 {k: np.asarray(v, dtype=np.float64) for k, v in final.items()})
+
+
+def price_scan(family, state: MarketState, utils, shocks):
+    """``family.step`` over the ``T`` ticks of ``utils`` / ``shocks`` as one
+    ``jax.lax.scan``; returns ``(final_state, prices)`` as device arrays.
+    Call it under ``jax.enable_x64(True)``: it computes in float64."""
+    import jax
+    import jax.numpy as jnp
+
+    def _step(carry, xs):
+        u, z = xs
+        carry, p = family.step(carry, u, z, xp=jnp)
+        return carry, p
+
+    # scan carries must keep a fixed shape: pre-broadcast every state
+    # leaf to the per-tick shock shape (no-op for single-path runs,
+    # (n_paths, n_pools) for Monte-Carlo fans)
+    state64 = {k: jnp.broadcast_to(jnp.asarray(v, dtype=jnp.float64),
+                                   shocks.shape[1:])
+               for k, v in state.items()}
+    return jax.lax.scan(
+        _step, state64, (jnp.asarray(utils, dtype=jnp.float64),
+                         jnp.asarray(shocks, dtype=jnp.float64)))
 
 
 def simulate_price_series(process, utilizations) -> np.ndarray:

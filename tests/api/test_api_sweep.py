@@ -52,6 +52,33 @@ def test_mini_sweep_deterministic_report():
         assert "policy" not in cell["metrics"]
 
 
+@pytest.mark.parametrize("backend,forks", [("jax", False), ("numpy", True)])
+def test_device_backed_sweeps_run_in_process(monkeypatch, backend, forks):
+    """A device belongs to one process: a sweep with a ``backend: "jax"``
+    job never opens a worker pool; a host-only sweep still forks."""
+    import multiprocessing
+
+    from repro.api import sweep
+    opened = []
+    real = multiprocessing.get_context
+
+    def spy(method=None):
+        ctx = real(method)
+        opened.append(method)
+        return ctx
+
+    monkeypatch.setattr(sweep.multiprocessing, "get_context", spy)
+    exp = ExperimentSpec(
+        name="device",
+        scenario=ScenarioSpec(workload="synthetic", horizon=300.0),
+        policies=(PolicySpec("first-fit"),
+                  PolicySpec("hlem-vmp", {"backend": backend})),
+        seeds=(0, 1))
+    report = run_experiment(exp, processes=2)
+    assert bool(opened) is forks
+    assert report["n_runs"] == 4
+
+
 def test_sweep_parallel_equals_serial():
     exp = _mini_experiment()
     serial = run_experiment(exp, processes=0)

@@ -194,3 +194,103 @@ def test_fused_pick_matches_scores_argmax():
         else:
             assert got == int(np.argmax(hlem_scores_np(free, mask, spot,
                                                        alpha)))
+
+
+# ---------------------------------------------------------------------------
+# device picks certified against the float64 oracle
+# ---------------------------------------------------------------------------
+def test_certified_pick_identical_rows_break_to_first():
+    from repro.core.hlem import certified_pick
+    free = np.array([[1.0, 2, 3, 4], [5, 6, 7, 8], [1, 2, 3, 4], [5, 6, 7, 8]])
+    spot = np.zeros((4, 4))
+    scores = np.array([0.2, 0.9, 0.2, 0.9], np.float32)
+    assert certified_pick(scores, 1e-3, free, spot) == 1
+
+
+def test_certified_pick_defers_a_near_tie_of_distinct_rows():
+    from repro.core.hlem import certified_pick
+    free = np.array([[1.0, 2, 3, 4], [5, 6, 7, 8], [5, 6, 7, 9]])
+    spot = np.zeros((3, 4))
+    scores = np.array([0.2, 0.9, 0.8995], np.float32)
+    assert certified_pick(scores, 1e-3, free, spot) is None
+    assert certified_pick(scores, 1e-4, free, spot) == 1
+
+
+@pytest.mark.parametrize("case", ["random", "near_uniform", "narrow_span"])
+def test_score_tolerance_bounds_float32_error(case):
+    """The bound the device returns covers the float32 error of every
+    score difference near the top, also where the entropies sit near 1
+    (weights amplify their rounding) and where large capacities differ by
+    little (float32 rounds the inputs on the scale of the span)."""
+    from repro.core.hlem import hlem_scores_tol_jax
+    rng = np.random.default_rng(hash(case) % 2 ** 32)
+    n = 3000
+    free = rng.uniform(0, 100, (n, 4))
+    if case == "near_uniform":
+        free[:] = [64.0, 98_304.0, 20_000.0, 800_000.0]
+        free[: n // 100] -= rng.uniform(0, 4, (n // 100, 4)) * [1, 2048, 10,
+                                                                1000]
+    elif case == "narrow_span":
+        free = 98_304.0 + rng.uniform(0, 0.05, (n, 4))
+    mask = rng.random(n) < 0.8
+    spot = rng.uniform(0, 1, (n, 4))
+    for alpha in (0.0, -0.5):
+        s64 = hlem_scores_np(free, mask, spot, alpha)
+        s32, tol = hlem_scores_tol_jax(free, mask, spot, np.float32(alpha))
+        s32 = np.asarray(s32, np.float64)
+        b = int(np.argmax(s64))
+        near = mask & (s64 >= s64[b] - 0.05)
+        err = np.abs((s32 - s32[b]) - (s64 - s64[b]))[near].max()
+        assert err <= float(tol)
+
+
+def test_jax_backend_breaks_float32_near_ties_like_the_oracle():
+    """Two hosts whose float64 scores differ by less than float32 can
+    resolve: the device pick defers to the exact pick and agrees."""
+    from repro.core.allocation import HlemVmp
+    from repro.core.hosts import HostPool
+    from repro.core.types import make_on_demand, resources
+    pool = HostPool()
+    pool.add_host(resources(16, 24_576, 10_000, 400_000))
+    pool.add_host(resources(16, 24_576, 10_000, 400_000))
+    pool.add_host(resources(16, 24_576, 10_000, 400_000))
+    # host 1 ends up 1e-7 RAM freer than host 0: float32 rounds the two
+    # rows (and scores) together, float64 prefers host 1
+    pool.place(make_on_demand(98, resources(1, 1_024.0000001, 10, 1_000),
+                              60.0), 0)
+    pool.place(make_on_demand(99, resources(1, 1_024, 10, 1_000), 60.0), 1)
+    pool.place(make_on_demand(97, resources(1, 2_048, 10, 1_000), 60.0), 2)
+    vm = make_on_demand(0, resources(1, 1_024, 10, 1_000), 60.0)
+    mask = pool.direct_mask_into(vm.demand).copy()
+    want = hlem_pick_np(pool.free(), mask, pool.spot_frac_view(), 0.0)
+    assert want == 1
+    pol = HlemVmp(backend="jax")
+    assert pol._score_pick(mask, vm, pool) == want
+    assert pol.device_picks == 1 and pol.device_fallbacks == 1
+
+
+@pytest.mark.parametrize("policy", ["hlem-vmp", "hlem-vmp-adjusted"])
+def test_jax_backend_decisions_equal_numpy_on_trace(policy):
+    """Every placement of a seeded trace run is the same with device
+    scoring as with the float64 host oracle: equal metrics rows and a
+    zero-divergence event log."""
+    from repro.api import ObsSpec, PolicySpec, RunSpec, ScenarioSpec, build
+    from repro.api.build import collect_row
+    from repro.obs.diff import first_divergence
+    runs = {}
+    for backend in ("numpy", "jax"):
+        spec = RunSpec(
+            scenario=ScenarioSpec(workload="trace", horizon=1800.0,
+                                  workload_params={"n_machines": 40,
+                                                   "sim_days": 0.05,
+                                                   "n_spot": 300}),
+            policy=PolicySpec(policy, {"backend": backend}),
+            obs=ObsSpec(events=True))
+        sim = build(spec, 0)
+        runs[backend] = (sim, collect_row(sim, sim.run(until=1800.0),
+                                          spec, 0))
+    (sim_np, row_np), (sim_jx, row_jx) = runs["numpy"], runs["jax"]
+    assert row_np == row_jx
+    assert row_np["allocations"] > 0
+    assert first_divergence(sim_np.events, sim_jx.events) is None
+    assert sim_jx.policy.device_picks > 0
