@@ -30,6 +30,7 @@ import numpy as np
 
 from .hlem import (
     certified_pick,
+    device_arg_bytes,
     hlem_pick_candidates_np,
     hlem_pick_np,
     hlem_scores_batch_np,
@@ -78,7 +79,13 @@ class AllocationPolicy:
     name = "abstract"
 
     #: telemetry hook (``repro.obs``); the build layer swaps in the live
-    #: tracer — batched-flush scoring volume feeds the counter registry
+    #: tracer.  In the batched flush (:meth:`find_first_direct`) the
+    #: ``allocation:flush/feasibility`` span times the feasibility matrix,
+    #: and the counters ``flush/batch_calls`` and ``flush/batch_rows``
+    #: count its calls and the queued VMs B it holds (their ratio is the
+    #: mean B; a pass with one candidate goes through :meth:`find_direct`
+    #: and is not counted).  :meth:`HlemVmp._score_pick` adds the device
+    #: pick's spans and ``pick/h2d_bytes``.
     tracer = NULL_TRACER
 
     def _pick(self, mask: np.ndarray, vm: Vm, pool: HostPool) -> int:
@@ -139,9 +146,10 @@ class AllocationPolicy:
         greedy commit loop re-decides only the suffix after each placement,
         so scoring work is one pass per placement instead of per queued VM."""
         nvm = len(vms)
-        if self.tracer.enabled:
-            self.tracer.counters.inc("alloc/batch_calls")
-            self.tracer.counters.inc("alloc/batch_rows", nvm)
+        tr = self.tracer
+        if tr.enabled:
+            tr.counters.inc("flush/batch_calls")
+            tr.counters.inc("flush/batch_rows", nvm)
         demands = np.empty((nvm, vms[0].demand.shape[0]))
         bids = np.empty(nvm)
         pids = np.empty(nvm, dtype=np.int64)
@@ -149,7 +157,11 @@ class AllocationPolicy:
             demands[b] = vm.demand
             bids[b] = vm.bid
             pids[b] = vm.pool
+        if tr.enabled:
+            tr.begin("allocation", "flush/feasibility")
         feas = pool.direct_mask_batch(demands, bids, pids)
+        if tr.enabled:
+            tr.end()
         any_row = feas.any(axis=1)
         for b in np.flatnonzero(any_row):
             return int(b), self._pick_direct(feas[b], vms[b], pool)
@@ -252,6 +264,18 @@ class HlemVmp(AllocationPolicy):
         return 0.0
 
     def _score_pick(self, mask: np.ndarray, vm: Vm, pool: HostPool) -> int:
+        """The HLEM pick over ``mask``: on the device (``backend="jax"``)
+        unless float32 cannot decide it, else on the host in float64.
+
+        With the tracer enabled, a device pick records three spans in the
+        ``allocation`` category: ``pick/call``, the scorer's call (host
+        conversion of its arguments, their transfer, the enqueue);
+        ``pick/readback``, the host blocked on the device and copying the
+        scores and the bound back (``np.asarray(scores)``, then
+        ``float(tol)``); and ``pick/host-exact``, the float64 pick after
+        a fallback.  The counter ``pick/h2d_bytes`` adds the bytes of the
+        scorer's host arguments, those the call copies to the device, in
+        the dtypes the device receives them in."""
         if not mask.any():
             return -1
         alpha = self._alpha_for(vm)
@@ -262,15 +286,33 @@ class HlemVmp(AllocationPolicy):
             free, spot_frac = pool.storage_views()
             padded = np.zeros(free.shape[0], dtype=bool)
             padded[: mask.size] = mask
+            tr = self.tracer
+            if tr.enabled:
+                tr.begin("allocation", "pick/call")
             scores, tol = hlem_scores_tol_jax(free, padded, spot_frac,
                                               np.float32(alpha))
+            if tr.enabled:
+                tr.end()
+                tr.counters.inc("pick/h2d_bytes", device_arg_bytes(
+                    free, padded, spot_frac, np.float32(alpha)))
             self.device_picks += 1
-            hid = certified_pick(np.asarray(scores), float(tol), free,
-                                 spot_frac)
+            if tr.enabled:
+                tr.begin("allocation", "pick/readback")
+            scores, tol = np.asarray(scores), float(tol)
+            if tr.enabled:
+                tr.end()
+            hid = certified_pick(scores, tol, free, spot_frac)
             if hid is not None:
                 return hid
             # a near-tie float32 cannot order: the exact pick decides
             self.device_fallbacks += 1
+            if tr.enabled:
+                tr.begin("allocation", "pick/host-exact")
+            hid = hlem_pick_np(pool.free(), mask, pool.spot_frac_view(),
+                               alpha)
+            if tr.enabled:
+                tr.end()
+            return hid
         return hlem_pick_np(pool.free(), mask, pool.spot_frac_view(), alpha)
 
     def _pick_direct(self, mask, vm, pool):

@@ -353,6 +353,17 @@ def hlem_scores_tol_jax(free, mask, spot_frac, alpha):
     return _scores_and_tol(free, mask, spot_frac, alpha)
 
 
+def device_arg_bytes(*args) -> int:
+    """Bytes a jitted call copies to the device for ``args``: each host
+    argument (array or scalar) in the dtype JAX gives it under the current
+    x64 setting (float64 host arrays arrive as float32 while x64 is off).
+    An argument already on the device (a ``jax.Array``) crosses nothing
+    and counts 0."""
+    return sum(int(np.size(a))
+               * jax.dtypes.canonicalize_dtype(np.result_type(a)).itemsize
+               for a in args if not isinstance(a, jax.Array))
+
+
 def certified_pick(scores: np.ndarray, tol: float, free: np.ndarray,
                    spot_frac: np.ndarray) -> int | None:
     """The float64 oracle's argmax read off float32 ``scores``, or None

@@ -389,11 +389,15 @@ class MarketSimulator:
         self._record()
 
     def _try_allocate(self, vm: Vm, fresh: bool) -> bool:
-        if self.obs.enabled:
-            self.obs.counters.inc("alloc/find_host")
+        tr = self.obs
+        if tr.enabled:
+            # one placement, the policy's picks inside it
+            tr.begin("allocation", "place")
         hid, needs_clearing = self.policy.find_host(
             vm, self.pool, self.now, allow_spot_clearing=True
         )
+        if tr.enabled:
+            tr.end(self.now)
         if hid < 0:
             self._enqueue_pending(vm, fresh, tested=True)
             return False
@@ -608,7 +612,6 @@ class MarketSimulator:
             prices = eng.tick(self.pool, t)
         if traced:
             tr.end(t, None)
-            tr.counters.inc("ticks")
             tr.begin("market-tick", "tick/wave")
         self.pool.set_pool_prices(prices)
         m = self.metrics
@@ -770,8 +773,14 @@ class MarketSimulator:
         vm = self.vms[vid]
         if gen != vm.generation or vm.state is not VmState.RUNNING:
             return  # finished / interrupted / preempt-warned since planning
+        tr = self.obs
+        if tr.enabled:
+            # the destination's placement, as in _try_allocate
+            tr.begin("allocation", "place")
         mask = self.pool.direct_mask_into(vm.demand, vm.bid, dst_pool)
         hid = self.policy._pick_direct(mask, vm, self.pool) if mask.any() else -1
+        if tr.enabled:
+            tr.end(self.now)
         if hid < 0:
             # no single host fits (pool-aggregate headroom was fragmented,
             # or same-time submissions took it): stay put, and black the VM
@@ -1131,7 +1140,10 @@ class MarketSimulator:
         retry, log = self._retry_pos, pool.gain_log
         fits = pool.fits_fast
         n_pending = len(pending)
+        tr = self.obs
         while i < n_pending:
+            if tr.enabled:
+                tr.begin("allocation", "flush/memo")
             # memo filter: keep only VMs that might fit under current state —
             # a VM that failed its last full test can only have become
             # feasible on a host whose free capacity increased since then.
@@ -1154,6 +1166,8 @@ class MarketSimulator:
                         retry[vm.id] = glen
                         continue
                 check.append(j)
+            if tr.enabled:
+                tr.end(self.now)
             if not check:
                 break
             # one feasibility matrix decides which VM places (a VM places iff

@@ -310,9 +310,6 @@ class MarketEngine:
         t0s = np.asarray(t0s, dtype=np.float64)
         t1s = np.asarray(t1s, dtype=np.float64)
         b = pids.size
-        if self.tracer.enabled:
-            self.tracer.counters.inc("billing/calls")
-            self.tracer.counters.inc("billing/spans", int(b))
         out = np.zeros(b, dtype=np.float64)
         k = self._n_ticks
         if b == 0 or k == 0:

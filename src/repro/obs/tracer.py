@@ -24,6 +24,16 @@ at all (regression-tested: metrics JSON equality, ``tests/obs``).  The
 :data:`NULL_TRACER` singleton is the default everywhere; sites never need
 a ``None`` check.
 
+Profiler mirror: while a :class:`Tracer` is enabled, every ``begin`` also
+opens a ``jax.profiler.TraceAnnotation`` named ``repro/<cat>/<name>``, and
+the matching ``end`` closes it.  Under a running ``jax.profiler`` trace the
+spans then sit, nested, on the trace's host plane, on the same clock as
+the device's operations, so a stretch in which the device idles can be put
+down to the program layer the host was in.  With no profiler running an
+annotation records nothing.  JAX is imported when the first ``Tracer`` is
+built, never by this module: without JAX the mirror is off and the tracer
+works as before.
+
 Nothing in this module draws randomness or mutates engine state: attaching
 a (fully enabled) tracer is observation-only, so traced and untraced runs
 of the same spec + seed produce identical metrics.
@@ -86,7 +96,8 @@ class NullTracer:
     def begin(self, cat: str, name: str) -> None:
         pass
 
-    def end(self, sim_t: float, args: Optional[dict] = None) -> None:
+    def end(self, sim_t: Optional[float] = None,
+            args: Optional[dict] = None) -> None:
         pass
 
     def instant(self, cat: str, name: str, sim_t: float,
@@ -106,6 +117,16 @@ class NullTracer:
 
 #: the default tracer everywhere a ``tracer`` attribute exists
 NULL_TRACER = NullTracer()
+
+
+def _profiler_annotation() -> Optional[Callable[[str], Any]]:
+    """``jax.profiler.TraceAnnotation``, or None where JAX is not
+    installed (the profiler mirror is then off)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 class Tracer:
@@ -143,22 +164,48 @@ class Tracer:
         #: optional live-progress hook: called as ``fn(sim_t, snapshot)``
         #: after every counter snapshot (the CLI's live line)
         self.on_snapshot: Optional[Callable[[float, dict], None]] = None
-        self._stack: List[list] = []       # [cat, name, t0, child_dur]
+        self._annotate = _profiler_annotation()
+        # [cat, name, t0, child_dur, profiler annotation or None,
+        #  len(spans) at begin]
+        self._stack: List[list] = []
+        #: records closed with no simulated time, not yet given their
+        #: enclosing span's
+        self._untimed = 0
         self._profile: Dict[Tuple[str, str], list] = {}  # -> [n, total, self]
         self._next_snap = 0.0 if counters_every is not None else None
 
     # ------------------------------------------------------------- spans
     def begin(self, cat: str, name: str) -> None:
-        self._stack.append([cat, name, self.clock(), 0.0])
+        note = None
+        if self._annotate is not None:
+            note = self._annotate(f"repro/{cat}/{name}")
+            note.__enter__()
+        self._stack.append([cat, name, self.clock(), 0.0, note,
+                            len(self.spans)])
 
-    def end(self, sim_t: float, args: Optional[dict] = None) -> None:
+    def end(self, sim_t: Optional[float] = None,
+            args: Optional[dict] = None) -> None:
+        """Close the innermost open span at simulated time ``sim_t``.
+
+        ``sim_t=None`` is for sites with no simulated clock of their own
+        (the allocation policy's): the span's record takes the simulated
+        time its enclosing span closes at, so it must have one."""
         t1 = self.clock()
-        cat, name, t0, child = self._stack.pop()
+        cat, name, t0, child, note, first = self._stack.pop()
+        if sim_t is None and not self._stack:
+            raise ValueError(f"span {cat}:{name} closed with no simulated "
+                             "time and no enclosing span to take it from")
+        if note is not None:
+            note.__exit__(None, None, None)
         dur = t1 - t0
         if self._stack:
             self._stack[-1][3] += dur     # accumulate into the parent
         self_dur = dur - child
         if self.keep_records:
+            if sim_t is None:
+                self._untimed += 1
+            elif self._untimed:
+                self._time_children(first, sim_t)
             self.spans.append(
                 (cat, name, t0 - self.epoch, dur, sim_t, self_dur, args))
         if self.profile_enabled:
@@ -169,6 +216,16 @@ class Tracer:
                 p[0] += 1
                 p[1] += dur
                 p[2] += self_dur
+
+    def _time_children(self, first: int, sim_t: float) -> None:
+        """Give the untimed records closed since index ``first`` (the
+        spans nested in the one closing) the simulated time ``sim_t``."""
+        spans = self.spans
+        for i in range(first, len(spans)):
+            rec = spans[i]
+            if rec[4] is None:
+                spans[i] = rec[:4] + (sim_t,) + rec[5:]
+                self._untimed -= 1
 
     def instant(self, cat: str, name: str, sim_t: float,
                 args: Optional[dict] = None) -> None:

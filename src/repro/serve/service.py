@@ -109,7 +109,8 @@ class ServeManager:
     engine and the fleet manager."""
 
     #: telemetry hook (``repro.obs``); the build layer swaps in the live
-    #: tracer — arrival/served/requeue counters feed the counter registry
+    #: tracer — an autoscaler action marks an instant (the request counts
+    #: are in the metrics)
     tracer = NULL_TRACER
     #: event recorder — request/serve/autoscale records for the flight log
     events = NULL_RECORDER
@@ -190,8 +191,6 @@ class ServeManager:
         self._ewma = (obs_rate if self._ewma is None
                       else self._alpha * obs_rate
                       + (1.0 - self._alpha) * self._ewma)
-        if self.tracer.enabled and n_new:
-            self.tracer.counters.inc("serve/arrivals", n_new)
         if self.events.enabled:
             self.events.emit(now, "request-arrive", a=float(n_new),
                              b=float(rate))
@@ -232,8 +231,6 @@ class ServeManager:
                     self.events.emit(now, "request-done", a=float(lat),
                                      b=float(r.target_tokens))
         m.requests_done += n_done
-        if self.tracer.enabled and n_done:
-            self.tracer.counters.inc("serve/done", n_done)
         while self._lat_window and self._lat_window[0][0] < now - self._window:
             self._lat_window.popleft()
         # -- sample ---------------------------------------------------------
@@ -274,7 +271,6 @@ class ServeManager:
                              aux=self.autoscaler.policy_name)
         if decided is not None:
             if self.tracer.enabled:
-                self.tracer.counters.inc("autoscale/actions")
                 self.tracer.instant("serve", "autoscale", now,
                                     {"from": old, "to": new})
             sim.fleet.set_target_units(sim, new, now)
@@ -308,8 +304,6 @@ class ServeManager:
             moved += 1
         m = sim.metrics
         m.requests_requeued += n_inflight
-        if self.tracer.enabled and n_inflight:
-            self.tracer.counters.inc("serve/requeued", n_inflight)
         if self.events.enabled:
             vm = sim.vms[vid]
             self.events.emit(now, "request-requeue", vm=vid,

@@ -54,6 +54,29 @@ def test_nested_span_self_time():
     assert prof[("inner", "child")] == [1, 3.0, 3.0]
 
 
+def test_untimed_span_takes_its_enclosing_spans_simulated_time():
+    tr = Tracer(clock=FakeClock())
+    tr.begin("event-loop", "dispatch")
+    tr.begin("allocation", "place")
+    tr.begin("allocation", "pick/call")
+    tr.end()
+    tr.end()                      # untimed too: both wait for dispatch
+    tr.begin("allocation", "pick/call")
+    tr.end()
+    tr.end(sim_t=42.0)
+    tr.begin("event-loop", "dispatch")
+    tr.begin("allocation", "place")
+    tr.end()
+    tr.end(sim_t=43.0)
+    assert [(n, sim) for _c, n, _t0, _d, sim, _s, _a in tr.spans] == [
+        ("pick/call", 42.0), ("place", 42.0), ("pick/call", 42.0),
+        ("dispatch", 42.0), ("place", 43.0), ("dispatch", 43.0)]
+    assert tr._untimed == 0
+    tr.begin("allocation", "place")
+    with pytest.raises(ValueError):
+        tr.end()
+
+
 def test_profile_only_mode_keeps_no_records():
     clk = FakeClock()
     tr = Tracer(keep_records=False, profile=True, clock=clk)
@@ -177,6 +200,6 @@ def test_trace_covers_subsystem_boundaries():
     assert "dispatch/price-tick" in names
     assert "plan/gradient-aware" in names
     c = tr.counters.values
-    assert c["events/total"] > 0 and c["ticks"] > 0
+    assert c["events/total"] > 0 and c["events/price-tick"] > 0
     assert any(k.startswith("interruptions/") for k in c)
     assert c.get("migrations/planned", 0) == c.get("migrations/started", 0)
