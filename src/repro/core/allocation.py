@@ -29,12 +29,14 @@ from typing import Sequence, Tuple
 import numpy as np
 
 from .hlem import (
+    ResidentScorer,
     certified_pick,
     device_arg_bytes,
+    dirty_capacity,
     hlem_pick_candidates_np,
     hlem_pick_np,
     hlem_scores_batch_np,
-    hlem_scores_tol_jax,
+    pack_pick,
 )
 from .hosts import HostPool
 from ..obs.tracer import NULL_TRACER
@@ -85,7 +87,8 @@ class AllocationPolicy:
     #: count its calls and the queued VMs B it holds (their ratio is the
     #: mean B; a pass with one candidate goes through :meth:`find_direct`
     #: and is not counted).  :meth:`HlemVmp._score_pick` adds the device
-    #: pick's spans and ``pick/h2d_bytes``.
+    #: pick's spans and the counters ``pick/h2d_bytes``,
+    #: ``pick/dirty_rows`` and ``pick/mirror_uploads``.
     tracer = NULL_TRACER
 
     def _pick(self, mask: np.ndarray, vm: Vm, pool: HostPool) -> int:
@@ -250,6 +253,11 @@ class HlemVmp(AllocationPolicy):
         #: the exact host pick (``backend="jax"`` only)
         self.device_picks = 0
         self.device_fallbacks = 0
+        #: the device mirror of ``_mirror_pool``'s scoring storage, current
+        #: up to position ``_mirror_pos`` of that pool's row log
+        self._mirror = ResidentScorer()
+        self._mirror_pool = None
+        self._mirror_pos = 0
 
     # -- phase 1 ------------------------------------------------------------
     def _rsdiff_ok(self, vm: Vm, pool: HostPool) -> np.ndarray:
@@ -267,38 +275,55 @@ class HlemVmp(AllocationPolicy):
         """The HLEM pick over ``mask``: on the device (``backend="jax"``)
         unless float32 cannot decide it, else on the host in float64.
 
+        The device scores a float32 mirror of the pool's whole storage
+        (rows past n masked off; its row count only changes when storage
+        doubles, so the scorer compiles a few times per run) that stays
+        there between picks: a pick sends one packed array, the mask and
+        the rows rewritten since the last pick (:func:`pack_pick`), and
+        reads one back.  A new pool, grown storage or more rewritten rows
+        than the packed array holds upload the whole mirror first.
+
         With the tracer enabled, a device pick records three spans in the
-        ``allocation`` category: ``pick/call``, the scorer's call (host
-        conversion of its arguments, their transfer, the enqueue);
-        ``pick/readback``, the host blocked on the device and copying the
-        scores and the bound back (``np.asarray(scores)``, then
-        ``float(tol)``); and ``pick/host-exact``, the float64 pick after
-        a fallback.  The counter ``pick/h2d_bytes`` adds the bytes of the
-        scorer's host arguments, those the call copies to the device, in
-        the dtypes the device receives them in."""
+        ``allocation`` category: ``pick/call``, the scorer's call (packing,
+        any upload, the transfer, the enqueue); ``pick/readback``, the host
+        blocked on the device and copying ``[scores..., tol]`` back; and
+        ``pick/host-exact``, the float64 pick after a fallback.  Counters:
+        ``pick/h2d_bytes``, the bytes the pick copies to the device (the
+        packed array and any upload); ``pick/dirty_rows``, rewritten rows
+        the packed arrays carried; ``pick/mirror_uploads``, whole
+        uploads."""
         if not mask.any():
             return -1
         alpha = self._alpha_for(vm)
         if self.backend == "jax":
-            # score the pool's whole storage (rows past n masked off): its
-            # row count only changes when storage doubles, so the jitted
-            # scorer compiles a few times per run, not once per host count
             free, spot_frac = pool.storage_views()
-            padded = np.zeros(free.shape[0], dtype=bool)
-            padded[: mask.size] = mask
             tr = self.tracer
             if tr.enabled:
                 tr.begin("allocation", "pick/call")
-            scores, tol = hlem_scores_tol_jax(free, padded, spot_frac,
-                                              np.float32(alpha))
+            mirror = self._mirror
+            changed = (pool.rows_since(self._mirror_pos)
+                       if pool is self._mirror_pool else None)
+            self._mirror_pool, self._mirror_pos = pool, pool.track_rows()
+            pool.compact_row_log(self._mirror_pos)
+            ids = [] if changed is None else sorted(set(changed))
+            sent = uploads = 0
+            if (changed is None or mirror.rows != free.shape[0]
+                    or len(ids) > dirty_capacity(free.shape[0])):
+                sent = mirror.upload(free, spot_frac)
+                uploads, ids = 1, []
+            packed = pack_pick(mask, alpha, ids, free, spot_frac)
+            out = mirror.scores_tol(packed)
             if tr.enabled:
                 tr.end()
-                tr.counters.inc("pick/h2d_bytes", device_arg_bytes(
-                    free, padded, spot_frac, np.float32(alpha)))
+                tr.counters.inc("pick/h2d_bytes", sent + device_arg_bytes(
+                    mirror.free, mirror.spot_frac, packed))
+                tr.counters.inc("pick/dirty_rows", len(ids))
+                tr.counters.inc("pick/mirror_uploads", uploads)
             self.device_picks += 1
             if tr.enabled:
                 tr.begin("allocation", "pick/readback")
-            scores, tol = np.asarray(scores), float(tol)
+            out = np.asarray(out)
+            scores, tol = out[:-1], float(out[-1])
             if tr.enabled:
                 tr.end()
             hid = certified_pick(scores, tol, free, spot_frac)

@@ -3,8 +3,10 @@
 Three implementations of the same math:
 
 * ``hlem_scores_np``  — pure-numpy oracle (readable, used as test reference),
-* ``hlem_scores_jax`` — vectorized/jitted JAX in float32 (the device path;
-  :func:`certified_pick` keeps its decisions those of the float64 oracle),
+* ``hlem_scores_jax`` — vectorized/jitted JAX in float32 (the device path,
+  run by ``hlem_scores_tol_jax_resident`` on a mirror of the pool kept on
+  the device; :func:`certified_pick` keeps its decisions those of the
+  float64 oracle),
 * ``repro.kernels.hlem_score`` — Pallas TPU kernel (tiled over hosts), checked
   against the numpy oracle in interpret mode and, on a TPU, by
   ``chip_smoke.py``.
@@ -351,6 +353,112 @@ def hlem_scores_tol_jax(free, mask, spot_frac, alpha):
     """(scores, tol): :func:`hlem_scores_jax` and a bound on the float32
     error of any difference of two of its scores."""
     return _scores_and_tol(free, mask, spot_frac, alpha)
+
+
+def dirty_capacity(rows: int) -> int:
+    """Changed rows one packed pick input carries for a storage of ``rows``:
+    a sixty-fourth of the rows (at least 16), whose 36 bytes each come to
+    under a fiftieth of a whole upload; more changed rows upload whole."""
+    return max(16, rows // 64)
+
+
+def _pick_layout(rows: int, d: int):
+    """(mask bytes padded to whole words, dirty-row capacity, total bytes)
+    of the packed pick input for a storage of ``rows`` x ``d``."""
+    head = -(-rows // 4) * 4
+    k_cap = dirty_capacity(rows)
+    return head, k_cap, head + 4 * (1 + k_cap * (1 + 2 * d))
+
+
+def pack_pick(mask: np.ndarray, alpha: float, ids, free: np.ndarray,
+              spot_frac: np.ndarray) -> np.ndarray:
+    """The one host array a resident pick sends, bytes of a length fixed by
+    the storage's shape: the candidate ``mask`` (a byte a row, padded to
+    the storage and to whole words), then little-endian words: ``alpha``
+    (float32), the ids of the rows rewritten since the last pick (unused
+    slots hold the row count) and their (free, spot_frac) rows in float32,
+    rounded by numpy as a host argument of the jitted scorer is."""
+    rows, d = free.shape
+    head, k_cap, size = _pick_layout(rows, d)
+    buf = np.zeros(size, dtype=np.uint8)
+    buf[: mask.size] = mask
+    words = buf[head:].view("<u4")
+    words[0] = np.float32(alpha).view(np.uint32)
+    k = len(ids)
+    words[1: 1 + k] = ids
+    words[1 + k: 1 + k_cap] = rows
+    vals = buf[head + 4 * (1 + k_cap):].view("<f4").reshape(k_cap, 2 * d)
+    vals[:k, :d] = free[ids]
+    vals[:k, d:] = spot_frac[ids]
+    return buf
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def hlem_scores_tol_jax_resident(free, spot_frac, packed):
+    """:func:`hlem_scores_tol_jax` on a float32 mirror of the storage kept
+    on the device: writes the changed rows that ``packed``
+    (:func:`pack_pick`) carries into ``free`` and ``spot_frac`` in place,
+    scores, and returns the new mirror and ``[scores..., tol]``."""
+    rows, d = free.shape
+    head, k_cap, _ = _pick_layout(rows, d)
+    # a byte a row and no arithmetic on it: unpacking bits here changes
+    # how XLA fuses the scorer, and its float32 scores then differ by ulps
+    # from :func:`hlem_scores_tol_jax`'s
+    mask = packed[:rows] != 0
+    words = jax.lax.bitcast_convert_type(packed[head:].reshape(-1, 4),
+                                         jnp.uint32)
+    alpha = jax.lax.bitcast_convert_type(words[0], jnp.float32)
+    ids = words[1: 1 + k_cap].astype(jnp.int32)
+    vals = jax.lax.bitcast_convert_type(words[1 + k_cap:], jnp.float32)
+    vals = vals.reshape(k_cap, 2 * d)
+
+    # one row at a time, as many as were sent: a scatter would have the
+    # TPU lay the mirror out row-major (4 -> 128 lanes) and copy it twice
+    def write_row(i, mirror):
+        f, s = mirror
+        f = jax.lax.dynamic_update_slice(f, vals[i, None, :d], (ids[i], 0))
+        s = jax.lax.dynamic_update_slice(s, vals[i, None, d:], (ids[i], 0))
+        return f, s
+
+    free, spot_frac = jax.lax.fori_loop(0, jnp.sum(ids < rows), write_row,
+                                        (free, spot_frac))
+    scores, tol = _scores_and_tol(free, mask, spot_frac, alpha)
+    # tol written into the slot after the scores: a concatenate lets XLA
+    # fuse tol's arithmetic otherwise, and round it by an ulp
+    out = jax.lax.dynamic_update_slice(jnp.pad(scores, (0, 1)), tol[None],
+                                       (rows,))
+    return free, spot_frac, out
+
+
+class ResidentScorer:
+    """A float32 mirror of a pool's (free, spot_frac) storage on the device,
+    for :func:`hlem_scores_tol_jax_resident`: each pick sends one packed
+    array and the rows changed since the previous pick, and reads one
+    array back.  The caller tracks which rows changed; a new pool, grown
+    storage or more changed rows than :func:`dirty_capacity` take
+    :meth:`upload` instead."""
+
+    def __init__(self):
+        self.free = self.spot_frac = None
+
+    @property
+    def rows(self) -> int:
+        return -1 if self.free is None else self.free.shape[0]
+
+    def upload(self, free: np.ndarray, spot_frac: np.ndarray) -> int:
+        """Replace the mirror by the whole storage; returns the bytes sent."""
+        host = (free.astype(np.float32), spot_frac.astype(np.float32))
+        self.free, self.spot_frac = jax.device_put(host)
+        return host[0].nbytes + host[1].nbytes
+
+    def scores_tol(self, packed: np.ndarray) -> jax.Array:
+        """Apply ``packed``'s rows and score: ``[scores..., tol]``, on the
+        device, its copy to the host already requested (it starts when the
+        program ends, not when the host asks)."""
+        self.free, self.spot_frac, out = hlem_scores_tol_jax_resident(
+            self.free, self.spot_frac, packed)
+        out.copy_to_host_async()
+        return out
 
 
 def device_arg_bytes(*args) -> int:

@@ -98,6 +98,12 @@ class HostPool:
         #: :meth:`compact_gain_log`, which bounds memory over long runs.
         self.gain_log: List[int] = []
         self._gain_base = 0
+        #: log of the rows :meth:`_refresh_row` rewrote in ``_free`` and
+        #: ``_spot_frac``, kept once a consumer calls :meth:`track_rows`
+        #: (a device mirror of that storage); positions are absolute, as in
+        #: the gain log, and ``_row_base`` counts entries dropped
+        self._row_log: Optional[List[int]] = None
+        self._row_base = 0
         # scratch buffers for zero-allocation mask computation
         self._scratch_ge = np.zeros((n, N_DIMS), dtype=bool)
         self._scratch_row = np.zeros(n, dtype=bool)
@@ -188,6 +194,14 @@ class HostPool:
             np.divide(self.spot_used[hid], self._tot_clamped[hid],
                       out=self._spot_frac[hid])
         self._rs_util_cpu[hid] = self.used[hid, 0] / self._rs_tot_cpu[hid]
+        log = self._row_log
+        if log is not None:
+            log.append(hid)
+            if len(log) > self._free.shape[0]:
+                # nobody has read it for a while: a consumer behind this
+                # point re-reads the whole storage instead
+                self._row_base += len(log)
+                log.clear()
 
     def _log_gain(self, hid: int) -> None:
         if self.active[hid]:
@@ -704,6 +718,30 @@ class HostPool:
         if drop > 0:
             del self.gain_log[:drop]
             self._gain_base += drop
+
+    # -- row log (device mirrors of the scoring storage) ---------------------
+    def track_rows(self) -> int:
+        """Start logging rewritten rows of :meth:`storage_views` (once;
+        later calls keep the log) and return the current position."""
+        if self._row_log is None:
+            self._row_log = []
+        return self._row_base + len(self._row_log)
+
+    def rows_since(self, pos: int) -> Optional[List[int]]:
+        """Row ids rewritten since ``pos`` (repeats included), or None
+        when the log no longer reaches back to ``pos``: the consumer then
+        has to read the whole storage again."""
+        start = pos - self._row_base
+        if self._row_log is None or start < 0:
+            return None
+        return self._row_log[start:]
+
+    def compact_row_log(self, min_live_pos: int) -> None:
+        """Drop row-log entries before ``min_live_pos``."""
+        drop = min(min_live_pos - self._row_base, len(self._row_log or ()))
+        if drop > 0:
+            del self._row_log[:drop]
+            self._row_base += drop
 
     # -- invariant checks (used by property tests) ---------------------------
     def check_invariants(self, now: Optional[float] = None) -> None:

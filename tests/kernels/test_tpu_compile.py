@@ -89,3 +89,18 @@ def test_float64_auction_scan_compiles_for_v5e(one_chip):
         text = _compiled_text(functools.partial(price_scan, AUCTION_FAMILY),
                               *args)
     assert "f64" in text
+
+
+@pytest.mark.parametrize("rows", [256, 16_384])
+def test_resident_pick_compiles_for_v5e_and_updates_in_place(one_chip, rows):
+    """The device pick at the market day's storage and at the paper
+    cluster's (12,600 hosts in 16,384 rows): its mirror is donated, so the
+    changed rows are written into it in place."""
+    from repro.core.hlem import hlem_scores_tol_jax_resident, pack_pick
+    packed = pack_pick(np.zeros(rows, dtype=bool), 0.0, [],
+                       np.zeros((rows, 4)), np.zeros((rows, 4)))
+    mirror = jax.ShapeDtypeStruct((rows, 4), jnp.float32, sharding=one_chip)
+    args = (mirror, mirror, jax.ShapeDtypeStruct(packed.shape, packed.dtype,
+                                                 sharding=one_chip))
+    compiled = hlem_scores_tol_jax_resident.lower(*args).compile()
+    assert "input_output_alias" in compiled.as_text()

@@ -15,7 +15,7 @@ import pytest
 from repro.api import (MigrationSpec, ObsSpec, PolicySpec, RunSpec,
                        ScenarioSpec, build)
 from repro.api.build import collect_row
-from repro.core.hlem import device_arg_bytes
+from repro.core.hlem import device_arg_bytes, dirty_capacity, pack_pick
 from repro.obs import Tracer, first_divergence
 
 SEED = 5
@@ -71,14 +71,27 @@ def test_pick_spans_count_the_policys_picks_over_a_window(runs):
     assert _count(prof, "pick/call", prof0) == picks - picks0
     assert _count(prof, "pick/readback", prof0) == picks - picks0
     assert _count(prof, "pick/host-exact", prof0) == falls - falls0
-    # every call scores the pool's whole storage
+    # every call sends one packed array, of a length fixed by the storage
+    # (a mask byte a row, then alpha, K row ids and their 2 x D float32
+    # values in words), and an upload sends the whole storage in float32
     free, spot_frac = sim.pool.storage_views()
-    per_call = device_arg_bytes(free, np.zeros(free.shape[0], dtype=bool),
-                                spot_frac, np.float32(0.0))
-    assert per_call == free.shape[0] * (2 * 4 * free.shape[1] + 1) + 4
-    sent = (sim.obs.counters.values["pick/h2d_bytes"]
-            - counters0.get("pick/h2d_bytes", 0))
-    assert sent == (picks - picks0) * per_call
+    rows, d = free.shape
+    k_cap = dirty_capacity(rows)
+    per_call = pack_pick(np.zeros(rows, dtype=bool), 0.0, [], free,
+                         spot_frac).nbytes
+    assert per_call == -(-rows // 4) * 4 + 4 * (1 + k_cap * (1 + 2 * d))
+    per_upload = rows * d * 4 * 2
+    c = sim.obs.counters.values
+
+    def sent(name):
+        return c[name] - counters0.get(name, 0)
+
+    assert sent("pick/h2d_bytes") == ((picks - picks0) * per_call
+                                      + sent("pick/mirror_uploads")
+                                      * per_upload)
+    # the run's first pick uploads; after it, rewritten rows ride along
+    assert c["pick/mirror_uploads"] >= 1
+    assert 0 < sent("pick/dirty_rows") <= (picks - picks0) * k_cap
 
 
 def test_h2d_bytes_leave_out_arguments_already_on_the_device():
