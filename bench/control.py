@@ -7,8 +7,11 @@ In one process, on the chip, for each seed: one window of the cell as
 ``bench/run.py`` drives it, then the numbers of the comparison twice over
 the same event logs: judged by the float64 reference (the program's
 readings, the lower ends) and with the reference computed in float32 in the
-program's place (the control's readings, the upper ends).  One JSON line
-per seed on standard output.  The benchmark's own runs never run this.
+program's place (the control's readings, the upper ends).  The
+configuration's own checks (``bench/checks/``) are judged both ways too,
+each given the precision, so each of their limits gets its two readings.
+One JSON line per seed on standard output.  The benchmark's own runs never
+run this.
 """
 import argparse
 import json

@@ -16,8 +16,9 @@ from the root of a checkout.  The cell, its configuration
    profiler and ``jax.profiler`` are on and the per-layer metrics are
    reported instead of the end-to-end ones.
 3. After the window: peak device memory, then the comparison with the plain
-   reference (``bench/check.py``), each number beside its limit on the last
-   lines of standard error and under ``checks`` in the result.
+   reference (``bench/check.py``) and the configuration's own checks
+   (``bench/checks/``), each number beside its limit on the last lines of
+   standard error and under ``checks`` in the result.
 
 The last line of standard output is the result, one JSON object.
 """
@@ -190,7 +191,7 @@ def run_cell(c: dict, seed: int, seconds: float, traced: bool,
         judged = check.judge(w.runs, c["config"])
         print("bench: readings " + json.dumps(judged["readings"]),
               file=sys.stderr)
-        checks = check.verdict(judged["numbers"])
+        checks = check.verdict(judged["numbers"], c["config"])
         result.update(attempted=judged["attempted"], failed=judged["failed"])
         result["correct"] = (w.sim_s > 0 and judged["attempted"] > 0
                              and all(v["ok"] for v in checks.values()))

@@ -57,9 +57,9 @@ def test_no_device_or_no_window_reads_nothing():
 
 def test_scorer_bytes_and_least_time():
     rows = 16_384
-    assert roofline.scorer_bytes(rows) == rows * 37 + 4
+    assert roofline.scorer_bytes(rows) == 615_432
     least = roofline.scorer_least_s(rows, "TPU v5 lite")
-    assert least == pytest.approx((rows * 37 + 4) / 819e9)
+    assert least == pytest.approx(615_432 / 819e9)
     assert least > roofline.scorer_flops(rows) / 197e12   # bound by bytes
     with pytest.raises(KeyError):
         roofline.peaks("TPU v9 imaginary")
