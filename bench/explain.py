@@ -17,12 +17,12 @@ lines, a traced run prints
   (``flush/batch_rows`` over ``flush/batch_calls``), and ``pick_h2d_bytes``,
   the bytes of the scorer's host arguments that cross to the device per
   device pick (``pick/h2d_bytes`` over the policy's ``device_picks``).
-  Both are ratios over the whole life of the window's simulators, a
-  continuous mix's warm-up included; each is left out where its
-  denominator is 0.
+  Both are ratios over the window (``Window.counters``, the counts the
+  per-layer readers get as ``ctx["counters"]``), a continuous mix's warm-up
+  left out; each is left out where its denominator is 0.
 
-``bench/run.py`` hands its per-layer readers neither the raw trace nor the
-program's counters, so these readings are not metrics of the benchmark.
+No entry of ``BENCHMARK.json`` names these readings, so they are not
+metrics of the benchmark.
 """
 import contextlib
 import json
@@ -89,19 +89,13 @@ def idle_by_span(planes: List[dict], top: int = 10) -> Dict[str, float]:
 def program_counters(w) -> Dict[str, float]:
     """The two counter ratios of the module docstring, from the window
     ``w`` that ``bench.window.run_window`` returns."""
-    c: Dict[str, float] = defaultdict(float)
-    picks = 0
-    for r in w.runs:
-        if getattr(r.sim.obs, "enabled", False):
-            for k, v in r.sim.obs.counters.values.items():
-                c[k] += v
-        picks += getattr(r.sim.policy, "device_picks", 0)
+    c = w.counters
     out = {}
-    if c["flush/batch_calls"] > 0:
-        out["flush_batch_rows"] = (c["flush/batch_rows"]
+    if c.get("flush/batch_calls", 0) > 0:
+        out["flush_batch_rows"] = (c.get("flush/batch_rows", 0)
                                    / c["flush/batch_calls"])
-    if c["pick/h2d_bytes"] > 0 and picks > 0:
-        out["pick_h2d_bytes"] = c["pick/h2d_bytes"] / picks
+    if c.get("pick/h2d_bytes", 0) > 0 and w.device_picks > 0:
+        out["pick_h2d_bytes"] = c["pick/h2d_bytes"] / w.device_picks
     return out
 
 

@@ -1,6 +1,6 @@
 """Self time of the event loop's ``event-loop:dispatch/*`` spans, as a
-percentage of the traced window.  Placement on arrival is inside it:
-``find_host`` has no span of its own."""
+percentage of the traced window.  Placement on arrival is a child span of
+its own (``allocation:place``), so its time is not counted here."""
 from bench.metrics._spans import self_share
 
 

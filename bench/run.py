@@ -160,7 +160,7 @@ def run_cell(c: dict, seed: int, seconds: float, traced: bool,
         trace = tracefile.reduce(tracefile.load(str(TRACE_DIR)))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         ctx = {"window_s": w.wall_s, "profile": w.profile,
-               "device_picks": w.device_picks,
+               "counters": w.counters, "device_picks": w.device_picks,
                "device_fallbacks": w.device_fallbacks, "trace": trace,
                "device_kind": device["kind"], "scorer_rows": scorer_rows(w)}
         for m in c["per_layer"]:

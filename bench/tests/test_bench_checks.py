@@ -1,5 +1,5 @@
-"""A configuration's own checks (``bench/checks/``): with none named, both
-cells judge as before; a check that claims a fleet's VMs holds them to its
+"""A configuration's own checks (``bench/checks/``): with none named, every
+cell judges as with an empty list; a check that claims a fleet's VMs holds them to its
 own part of ``stated`` and lifts the base input rules off them alone; and
 every way a check could weaken the comparison judges the run wrong."""
 import copy
@@ -23,19 +23,26 @@ SEED, HORIZON = 5, 14400.0
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_no_checks_judge_as_an_empty_list(cell):
+    """A configuration without its ``checks`` key, and with ``"checks":
+    []``, judge alike; one that names no check judges like both."""
     from bench.window import run_window
 
     c = small_cell(cell)
     w = run_window(c["config"]["spec"], c["traffic"], 2_147_483_693, 1.0)
-    empty = dict(c["config"], checks=[])
-    assert "checks" not in c["config"]
-    base = check.judge(w.runs, c["config"])
+    bare = {k: v for k, v in c["config"].items() if k != "checks"}
+    empty = dict(bare, checks=[])
+    base = check.judge(w.runs, bare)
     assert base["attempted"] > 0
     assert check.judge(w.runs, empty) == base
     assert check.verdict(base["numbers"], empty) == \
         check.verdict(base["numbers"]) == \
-        check.verdict(base["numbers"], c["config"])
-    assert check.limits(empty) == check.limits()
+        check.verdict(base["numbers"], bare)
+    assert check.limits(empty) == check.limits(bare) == check.limits()
+    if not check.check_names(c["config"]):
+        assert check.judge(w.runs, c["config"]) == base
+        assert check.verdict(base["numbers"], c["config"]) == \
+            check.verdict(base["numbers"])
+        assert check.limits(c["config"]) == check.limits()
 
 
 def fleet_config(checks=()):

@@ -6,8 +6,8 @@ import types
 
 import pytest
 
-from bench import explain, run, tracefile
-from bench.tests._small import CPU, small_cell
+from bench import explain, run, tracefile, window
+from bench.tests._small import CELLS, CPU, small_cell
 from bench.tests.test_bench_tracefile import synthetic
 
 
@@ -89,30 +89,60 @@ def test_program_readers_read_nothing_from_a_program_without_them(metric):
     assert run.reader(metric)(ctx) is None
 
 
-def _window(*sims):
-    return types.SimpleNamespace(
-        runs=[types.SimpleNamespace(sim=s) for s in sims])
-
-
-def _sim(counters, picks, enabled=True):
-    obs = types.SimpleNamespace(
-        enabled=enabled, counters=types.SimpleNamespace(values=counters))
-    return types.SimpleNamespace(
-        obs=obs, policy=types.SimpleNamespace(device_picks=picks))
+def _window(counters, picks):
+    return types.SimpleNamespace(counters=counters, device_picks=picks)
 
 
 def test_program_counters_are_ratios_over_the_windows_runs():
-    w = _window(
-        _sim({"flush/batch_calls": 3, "flush/batch_rows": 20,
-              "pick/h2d_bytes": 10 * 8452}, 10),
-        _sim({"flush/batch_calls": 1, "flush/batch_rows": 6,
-              "pick/h2d_bytes": 6 * 8452}, 6))
-    assert explain.program_counters(w) == pytest.approx(
+    c = {}
+    window._add_counters(c, {"flush/batch_calls": 3, "flush/batch_rows": 20,
+                             "pick/h2d_bytes": 10 * 8452})
+    # a continuous mix's run: what it counted before the window is left out
+    window._add_counters(c, {"flush/batch_calls": 2, "flush/batch_rows": 9,
+                             "pick/h2d_bytes": 7 * 8452},
+                         {"flush/batch_calls": 1, "flush/batch_rows": 3,
+                          "pick/h2d_bytes": 8452})
+    assert c == {"flush/batch_calls": 4, "flush/batch_rows": 26,
+                 "pick/h2d_bytes": 16 * 8452}
+    assert explain.program_counters(_window(c, 16)) == pytest.approx(
         {"flush_batch_rows": 6.5, "pick_h2d_bytes": 8452.0})
-    # a program without the counters, or a run without a tracer
+    # a program without the counters, or a window without a tracer
     assert explain.program_counters(_window(
-        _sim({"alloc/batch_calls": 4, "alloc/batch_rows": 26}, 10),
-        _sim({"flush/batch_calls": 4}, 10, enabled=False))) == {}
+        {"alloc/batch_calls": 4, "alloc/batch_rows": 26}, 10)) == {}
+    assert explain.program_counters(_window({}, 10)) == {}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_reader_sees_the_window_counters_that_explain_prints(
+        cell, monkeypatch, tmp_path):
+    """A per-layer reader gets ``ctx["counters"]``: the counts behind
+    ``bench/explain.py``'s ratios, taken over the window alone."""
+    monkeypatch.setattr(run, "TRACE_DIR", tmp_path / "trace")
+    seen = []
+
+    def reader(metric):
+        return seen.append
+    monkeypatch.setattr(run, "reader", reader)
+    with explain.explaining() as held:
+        run.run_cell(small_cell(cell), 23, 0.5, True, CPU)
+    w = held[0]
+    ctx = seen[0]
+    c = ctx["counters"]
+    assert c == w.counters and c["pick/h2d_bytes"] > 0
+    from_ctx = {"pick_h2d_bytes": c["pick/h2d_bytes"] / ctx["device_picks"]}
+    if c.get("flush/batch_calls", 0) > 0:
+        from_ctx["flush_batch_rows"] = (c["flush/batch_rows"]
+                                        / c["flush/batch_calls"])
+    assert explain.program_counters(w) == from_ctx
+    whole = {}
+    for r in w.runs:
+        window._add_counters(whole, r.sim.obs.counters.values)
+    if small_cell(cell)["traffic"]["mode"] == "continuous":
+        # the warm-up's picks are the simulator's, not the window's
+        assert 0 < c["pick/h2d_bytes"] < whole["pick/h2d_bytes"]
+        assert 0 < ctx["device_picks"] < w.runs[0].sim.policy.device_picks
+    else:
+        assert c == whole
 
 
 def test_explaining_prints_idle_by_span_and_holds_the_window(

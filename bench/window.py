@@ -15,7 +15,10 @@ A traffic mix is a file of parameters (``bench/traffic/<name>.json``):
   ``seed + WARMUP_SEED_OFFSET``, to ``warmup_sim_s`` and drops it.
 
 Every simulator records its event log, which the check reads after the
-window; with ``traced`` the span profiler is on too.
+window; with ``traced`` the span profiler and the program's counters are on
+too, and the window keeps what each took inside it: a continuous mix's
+readings less those at the window's start, a sweep's days whole (each is
+built inside the window).
 """
 from __future__ import annotations
 
@@ -50,6 +53,8 @@ class Window:
     device_fallbacks: int = 0
     #: span ``(cat, name) -> [count, total_s, self_s]`` over the window
     profile: Dict[Tuple[str, str], list] = field(default_factory=dict)
+    #: the program's counters ``name -> value`` over the window
+    counters: Dict[str, float] = field(default_factory=dict)
     setup_done: float = 0.0   # perf_counter at the first timed chunk
     #: perf_counter at the ends of set-up's steps: ``built``, ``warm``
     marks: Dict[str, float] = field(default_factory=dict)
@@ -83,6 +88,16 @@ def _add_profile(into: dict, now: dict, before: Optional[dict] = None):
             cur[i] += v[i] - b[i]
 
 
+def _counters(sim) -> Dict[str, float]:
+    on = getattr(sim.obs, "enabled", False)
+    return dict(sim.obs.counters.values) if on else {}
+
+
+def _add_counters(into: dict, now: dict, before: Optional[dict] = None):
+    for k, v in now.items():
+        into[k] = into.get(k, 0) + v - (before or {}).get(k, 0)
+
+
 def _horizon(spec) -> float:
     if spec.scenario.horizon is None:
         raise ValueError("a configuration under a sweep mix needs a horizon")
@@ -112,7 +127,7 @@ def run_window(spec_dict: dict, traffic: dict, seed: int, seconds: float,
         w.marks["warm"] = time.perf_counter()
         run = Run(sim, seed, t_start=warm, t_end=warm, n_hosts0=n0)
         w.runs.append(run)
-        picks0, prof0 = _picks(sim), _profile(sim)
+        picks0, prof0, count0 = _picks(sim), _profile(sim), _counters(sim)
         horizon = spec.scenario.horizon
         if on_start:
             on_start()
@@ -134,6 +149,7 @@ def run_window(spec_dict: dict, traffic: dict, seed: int, seconds: float,
         w.device_picks, w.device_fallbacks = (p1[0] - picks0[0],
                                               p1[1] - picks0[1])
         _add_profile(w.profile, _profile(sim), prof0)
+        _add_counters(w.counters, _counters(sim), count0)
         w.sim_s = run.t_end - run.t_start
         return w
 
@@ -176,5 +192,6 @@ def run_window(spec_dict: dict, traffic: dict, seed: int, seconds: float,
         w.device_picks += p[0]
         w.device_fallbacks += p[1]
         _add_profile(w.profile, _profile(run.sim))
+        _add_counters(w.counters, _counters(run.sim))
         w.sim_s += run.t_end - run.t_start
     return w
