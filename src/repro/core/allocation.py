@@ -88,7 +88,9 @@ class AllocationPolicy:
     #: mean B; a pass with one candidate goes through :meth:`find_direct`
     #: and is not counted).  :meth:`HlemVmp._score_pick` adds the device
     #: pick's spans and the counters ``pick/h2d_bytes``,
-    #: ``pick/dirty_rows`` and ``pick/mirror_uploads``.
+    #: ``pick/dirty_rows``, ``pick/mirror_uploads`` and
+    #: ``pick/host_exact_rows`` (the candidates of the float64 picks after
+    #: fallbacks: over ``device_fallbacks``, their mean count).
     tracer = NULL_TRACER
 
     def _pick(self, mask: np.ndarray, vm: Vm, pool: HostPool) -> int:
@@ -291,7 +293,8 @@ class HlemVmp(AllocationPolicy):
         ``pick/h2d_bytes``, the bytes the pick copies to the device (the
         packed array and any upload); ``pick/dirty_rows``, rewritten rows
         the packed arrays carried; ``pick/mirror_uploads``, whole
-        uploads."""
+        uploads; ``pick/host_exact_rows``, the candidates of the float64
+        picks."""
         if not mask.any():
             return -1
         alpha = self._alpha_for(vm)
@@ -333,6 +336,8 @@ class HlemVmp(AllocationPolicy):
             self.device_fallbacks += 1
             if tr.enabled:
                 tr.begin("allocation", "pick/host-exact")
+                tr.counters.inc("pick/host_exact_rows",
+                                int(np.count_nonzero(mask)))
             hid = hlem_pick_np(pool.free(), mask, pool.spot_frac_view(),
                                alpha)
             if tr.enabled:

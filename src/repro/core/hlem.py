@@ -153,6 +153,7 @@ def hlem_pick_candidates_np(
     idx: np.ndarray,
     spot_frac: np.ndarray,
     alpha: float = 0.0,
+    m_cutover: int | None = None,   # override PICK_NP_M_CUTOVER (tests)
 ) -> int:
     """:func:`hlem_pick_np` over an explicit candidate-id array (the policy
     layer already holds ``flatnonzero`` of its masks).
@@ -165,11 +166,46 @@ def hlem_pick_candidates_np(
         return -1
     if m == 1:
         return int(idx[0])  # degenerate candidate set: any weighting agrees
+    weights = _candidate_weights_np(free, idx, alpha == 0.0, m_cutover)
+    if weights is None:
+        # all dims degenerate: HS identical for every candidate and the
+        # adjustment is off, so the argmax tie-breaks to the first
+        return int(idx[0])
+    c_std, w = weights
+    hs = np.dot(c_std, w, out=_WS.hs[:m])
+    if alpha != 0.0:
+        sl = np.take(np.asarray(spot_frac, dtype=np.float64), idx, axis=0) @ w
+        hs = hs * (1.0 + alpha * sl)
+    return int(idx[np.argmax(hs)])
+
+
+def _candidate_weights_np(free, idx, flat_exit: bool,
+                          m_cutover: int | None = None):
+    """:func:`hlem_weights_np` over the m >= 2 candidates ``idx``, bit for
+    bit: (standardized candidate rows C~ (m, D), a workspace view valid
+    until the next pick; weights w (D,)).  None where ``flat_exit`` is set
+    and every dimension is degenerate.
+
+    numpy reduces an (m, D) array over axis 0 as m calls of a D-wide loop.
+    Above ``PICK_NP_M_CUTOVER`` candidates the column statistics avoid
+    that: min and max (exact in any order) run along a copy laid out a row
+    per dimension, and each column sum is an ``einsum`` over the rows,
+    which adds candidate after candidate into the D sums, as the oracle's
+    axis-0 sum does (a sum along the copy's rows would add pairwise), in
+    one pass."""
+    m = idx.size
     free = np.asarray(free, dtype=np.float64)
     d = free.shape[1]
     _WS.ensure(m, d)
+    by_dim = m > (PICK_NP_M_CUTOVER if m_cutover is None else m_cutover)
     sel = np.take(free, idx, axis=0, out=_WS.sel[:m])
-    lo, hi = sel.min(axis=0), sel.max(axis=0)
+    if by_dim:
+        # the leading m * D elements of a buffer c_std overwrites below
+        dims = _WS.tmp.reshape(-1)[: d * m].reshape(d, m)
+        np.copyto(dims, sel.T)
+        lo, hi = dims.min(axis=1), dims.max(axis=1)
+    else:
+        lo, hi = sel.min(axis=0), sel.max(axis=0)
     span = hi - lo
     nondegen = span > _EPS
     c_std = _WS.tmp[:m]
@@ -177,16 +213,14 @@ def hlem_pick_candidates_np(
     if nondegen.all():
         np.divide(c_std, span, out=c_std)
     else:
-        if alpha == 0.0 and not nondegen.any():
-            # all dims degenerate: HS identical for every candidate and the
-            # adjustment is off, so the argmax tie-breaks to the first
-            return int(idx[0])
+        if flat_exit and not nondegen.any():
+            return None
         np.divide(c_std, np.where(nondegen, span, 1.0), out=c_std)
         np.copyto(c_std, 1.0, where=~nondegen)
     # each column sums to >= 1 (its max candidate standardizes to 1.0, or the
     # degenerate all-ones case sums to m), so the col > eps guard of the
     # oracle never fires and plain division is value-identical
-    col = c_std.sum(axis=0)
+    col = np.einsum("ij->j", c_std) if by_dim else c_std.sum(axis=0)
     # p reuses the gather buffer (sel is not read past this point); the
     # entropy chain below computes where(p > eps, p*log(max(p, eps)), 0)
     # elementwise-identically with zero allocation
@@ -197,16 +231,20 @@ def hlem_pick_candidates_np(
     np.multiply(p, plogp, out=plogp)
     np.copyto(plogp, 0.0, where=small)
     k = 1.0 / math.log(m)
-    e = -k * plogp.sum(axis=0)
+    e = -k * (np.einsum("ij->j", plogp) if by_dim else plogp.sum(axis=0))
     g = 1.0 - e
     gsum = g.sum()
     w = g / gsum if gsum > _EPS else np.full(d, 1.0 / d)
-    hs = np.dot(c_std, w, out=_WS.hs[:m])
-    if alpha != 0.0:
-        sl = np.take(np.asarray(spot_frac, dtype=np.float64), idx, axis=0) @ w
-        hs = hs * (1.0 + alpha * sl)
-    return int(idx[np.argmax(hs)])
+    return c_std, w
 
+
+#: candidate count above which :func:`hlem_pick_candidates_np` takes its
+#: column statistics without numpy's axis-0 reductions (the same values bit
+#: for bit).  The copy and the einsum calls cost more than they save on
+#: small sets: on a TPU v5e host the two ways cost the same between 64 and
+#: 128 candidates, and the new one 0.44 of the old at 12,600
+#: (``tools/host_pick_bench.py`` times both on the host it runs on).
+PICK_NP_M_CUTOVER = 96
 
 #: fleet-size crossover for the batched numpy scorer: above this many hosts
 #: the (B, n, D) broadcast core loses to a compressed per-row pass (its

@@ -12,7 +12,9 @@ from repro.core import (
     hlem_select_jax,
     hlem_select_np,
 )
-from repro.core.hlem import hlem_pick_np
+from repro.core import hlem
+from repro.core.hlem import (PICK_NP_M_CUTOVER, hlem_pick_candidates_np,
+                             hlem_pick_np, hlem_weights_np)
 
 BIG = 3.4e38
 
@@ -194,6 +196,147 @@ def test_fused_pick_matches_scores_argmax():
         else:
             assert got == int(np.argmax(hlem_scores_np(free, mask, spot,
                                                        alpha)))
+
+
+def _pick_cases(rng, m):
+    """(name, free, mask, spot) candidate sets of m hosts among m + m // 3
+    rows with trace-like capacities, and a set of two."""
+    n = m + m // 3
+    cap = rng.choice([16.0, 32.0, 64.0], size=n)[:, None] * np.array(
+        [1.0, 1_536.0, 625.0, 25_000.0])
+    free = cap * rng.uniform(0.0, 1.0, (n, 4))
+    spot = rng.uniform(0.0, 0.5, (n, 4))
+    mask = np.zeros(n, dtype=bool)
+    mask[rng.permutation(n)[:m]] = True
+    partly = free.copy()
+    partly[:, 1] = 3_072.0                    # one dimension degenerate
+    flat = np.broadcast_to(free[0], free.shape).copy()    # every dimension
+    # seven distinct (free, spot) rows, each held by two candidates or none:
+    # the best score is held by several hosts, and the pick breaks the tie
+    # to the first
+    pairs = rng.integers(0, 7, m)
+    pairs[1::2] = pairs[0::2][: m // 2]
+    pairs[-1] = pairs[0] if m % 2 else pairs[-1]
+    kind = rng.integers(0, 7, n)
+    kind[mask] = rng.permutation(pairs)
+    two = np.zeros(n, dtype=bool)
+    two[np.flatnonzero(mask)[:2]] = True
+    return [("random", free, mask, spot), ("partly", partly, mask, spot),
+            ("flat", flat, mask, spot),
+            ("duplicates", free[kind], mask, spot[kind]),
+            ("two", free, two, spot)]
+
+
+@pytest.mark.parametrize("m,m_cutover", [
+    (PICK_NP_M_CUTOVER // 4, None),           # rows, below the cutover
+    (PICK_NP_M_CUTOVER + 1_000, None),        # columns, above it
+    (300, 0),                                 # columns forced
+])
+def test_pick_layouts_match_the_oracle(m, m_cutover):
+    """Both layouts of the fused pick take the oracle's host, ties to the
+    first, and derive its standardized capacities and weights bit for
+    bit."""
+    rng = np.random.default_rng(m)
+    for name, free, mask, spot in _pick_cases(rng, m):
+        idx = np.flatnonzero(mask)
+        c_want, w_want = hlem_weights_np(free, mask)
+        c_got, w_got = hlem._candidate_weights_np(free, idx, False,
+                                                  m_cutover)
+        assert np.array_equal(w_got, w_want), name
+        assert np.array_equal(c_got, c_want[mask]), name
+        for alpha in (0.0, -0.5, 0.7):
+            scores = hlem_scores_np(free, mask, spot, alpha)
+            want = int(np.argmax(scores))
+            if name == "duplicates":
+                assert np.count_nonzero(scores == scores[want]) > 1
+            assert hlem_pick_candidates_np(free, idx, spot, alpha,
+                                           m_cutover) == want, (name, alpha)
+
+
+def _decision_spec(workload: str, backend: str):
+    from repro.api import (MigrationSpec, ObsSpec, PolicySpec, RunSpec,
+                           ScenarioSpec)
+    policy = PolicySpec("hlem-vmp-adjusted",
+                        {"alpha": -0.5, "backend": backend})
+    if workload == "trace":
+        return RunSpec(
+            scenario=ScenarioSpec(workload="trace", horizon=3600.0,
+                                  workload_params={"n_machines": 300,
+                                                   "load_per_machine": 3.6,
+                                                   "sim_days": 0.05,
+                                                   "n_spot": 300}),
+            policy=policy, obs=ObsSpec(events=True))
+    return RunSpec(
+        scenario=ScenarioSpec(workload="market", regime="volatile",
+                              n_pools=4, from_advisor=True,
+                              workload_params={"fleet_scale": 1.7},
+                              bid={"strategy": "randomized",
+                                   "params": {"lo": 0.45}},
+                              horizon=3600.0),
+        policy=policy, migration=MigrationSpec("gradient-aware"),
+        obs=ObsSpec(events=True))
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+@pytest.mark.parametrize("workload", ["trace", "market"])
+def test_column_pick_changes_no_decision_in_a_run(workload, backend,
+                                                  monkeypatch):
+    """A trace run at 300 machines and an hour of a volatile market day
+    make every decision alike with the pick's cutover at its default,
+    forced to 0 (the column statistics for every set) and above every set
+    (numpy's axis-0 reductions for every set): zero-divergence event
+    logs."""
+    from repro.api import build
+    from repro.obs.diff import first_divergence
+    spec = _decision_spec(workload, backend)
+    default = build(spec, 0)
+    default.run(until=3600.0)
+    assert len(default.events) > 0
+    weights = hlem._candidate_weights_np
+    for cutover in (0, 2 ** 62):
+        weighed = []
+
+        def spy(free, idx, flat_exit, m_cutover=None):
+            weighed.append(idx.size)
+            return weights(free, idx, flat_exit, m_cutover)
+
+        monkeypatch.setattr(hlem, "PICK_NP_M_CUTOVER", cutover)
+        monkeypatch.setattr(hlem, "_candidate_weights_np", spy)
+        forced = build(spec, 0)
+        forced.run(until=3600.0)
+        assert len(weighed) > 0
+        assert first_divergence(default.events, forced.events) is None
+        if backend == "jax":
+            # the float64 picks after fallbacks ran
+            assert forced.policy.device_fallbacks == len(weighed)
+
+
+def test_host_exact_rows_counts_the_fallbacks_candidates():
+    from repro.core.allocation import HlemVmp
+    from repro.core.hosts import HostPool
+    from repro.core.types import make_on_demand, resources
+    from repro.obs import Tracer
+    pool = HostPool()
+    for _ in range(4):
+        pool.add_host(resources(16, 24_576, 10_000, 400_000))
+    # hosts 0 and 1 float32 cannot order (as in the test below); host 3
+    # is full
+    pool.place(make_on_demand(98, resources(1, 1_024.0000001, 10, 1_000),
+                              60.0), 0)
+    pool.place(make_on_demand(99, resources(1, 1_024, 10, 1_000), 60.0), 1)
+    pool.place(make_on_demand(97, resources(1, 2_048, 10, 1_000), 60.0), 2)
+    pool.place(make_on_demand(96, resources(16, 1_024, 10, 1_000), 60.0), 3)
+    vm = make_on_demand(0, resources(1, 1_024, 10, 1_000), 60.0)
+    mask = pool.direct_mask_into(vm.demand).copy()
+    assert np.count_nonzero(mask) == 3
+    pol = HlemVmp(backend="jax")
+    pol.tracer = Tracer(keep_records=False, profile=True)
+    pol.tracer.begin("allocation", "place")   # the pick's spans nest in it
+    assert pol._score_pick(mask, vm, pool) == 1
+    pol.tracer.end(0.0)
+    assert pol.device_fallbacks == 1
+    assert pol.tracer.counters.values["pick/host_exact_rows"] == 3
+    assert pol.tracer.profile()[("allocation", "pick/host-exact")][0] == 1
 
 
 # ---------------------------------------------------------------------------

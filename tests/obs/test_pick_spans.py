@@ -89,6 +89,11 @@ def test_pick_spans_count_the_policys_picks_over_a_window(runs):
     assert sent("pick/h2d_bytes") == ((picks - picks0) * per_call
                                       + sent("pick/mirror_uploads")
                                       * per_upload)
+    # a float64 pick after a fallback scores the candidates, at least the
+    # two hosts float32 could not order
+    hosts = sim.pool.free().shape[0]
+    assert (2 * (falls - falls0) <= sent("pick/host_exact_rows")
+            <= (falls - falls0) * hosts)
     # the run's first pick uploads; after it, rewritten rows ride along
     assert c["pick/mirror_uploads"] >= 1
     assert 0 < sent("pick/dirty_rows") <= (picks - picks0) * k_cap
