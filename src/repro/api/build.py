@@ -37,8 +37,8 @@ from .workloads import WORKLOAD_REGISTRY
 def build_tracer(obs: Optional[ObsSpec]) -> Optional[Tracer]:
     """A fresh :class:`~repro.obs.tracer.Tracer` for an :class:`ObsSpec`,
     or None when none of the tracer switches are on (the simulator then
-    runs the plain untraced loop — an events-only spec records the flight
-    log without ever building a tracer).  ``keep_records`` follows
+    keeps the inert ``NULL_TRACER`` — an events-only spec records the
+    flight log without ever building a tracer).  ``keep_records`` follows
     ``trace`` — profile- or counters-only modes still time spans but retain
     no per-span records, so memory stays bounded at trace scale."""
     if obs is None or not (obs.trace or obs.profile
@@ -117,32 +117,6 @@ def build(spec: RunSpec, seed: int) -> MarketSimulator:
         config=SimConfig(record_timeline=False, **dict(scenario.sim_params)),
         engine=engine, migration=migration, rebid=rebid,
         fleet=fleet, faults=faults, serve=serve, obs=obs, events=events)
-    if obs is not None:
-        # one tracer per run, shared by every subsystem so spans nest and
-        # counters land in a single registry; components are fresh per
-        # build, so instance-level attachment cannot leak across runs
-        sim.policy.tracer = obs
-        if engine is not None:
-            engine.tracer = obs
-        if migration is not None:
-            migration.tracer = obs
-        if fleet is not None:
-            fleet.tracer = obs
-        if serve is not None:
-            serve.tracer = obs
-    if events is not None:
-        # one flight recorder per run, shared by every emit site — the
-        # same attach pattern as the tracer (fresh components, no leaks)
-        if engine is not None:
-            engine.events = events
-        if migration is not None:
-            migration.events = events
-        if fleet is not None:
-            fleet.events = events
-        if faults is not None:
-            faults.events_log = events
-        if serve is not None:
-            serve.events = events
     WORKLOAD_REGISTRY.get(scenario.workload)(sim, scenario, seed)
     return sim
 

@@ -379,7 +379,7 @@ class ObsSpec(_SpecBase):
     ``events`` is a fourth, independent switch: it attaches an
     :class:`~repro.obs.eventlog.EventLog` flight recorder (structured
     lifecycle/market event log) without building a tracer — an events-only
-    spec still runs the plain untraced event loop."""
+    spec keeps the inert ``NULL_TRACER``."""
 
     trace: bool = False
     profile: bool = False

@@ -80,7 +80,7 @@ def feasibility_masks(vm: Vm, pool: HostPool, now: float):
 class AllocationPolicy:
     name = "abstract"
 
-    #: telemetry hook (``repro.obs``); the build layer swaps in the live
+    #: telemetry hook (``repro.obs``); the simulator hands in its live
     #: tracer.  In the batched flush (:meth:`find_first_direct`) the
     #: ``allocation:flush/feasibility`` span times the feasibility matrix,
     #: and the counters ``flush/batch_calls`` and ``flush/batch_rows``
@@ -152,9 +152,8 @@ class AllocationPolicy:
         so scoring work is one pass per placement instead of per queued VM."""
         nvm = len(vms)
         tr = self.tracer
-        if tr.enabled:
-            tr.counters.inc("flush/batch_calls")
-            tr.counters.inc("flush/batch_rows", nvm)
+        tr.counters.inc("flush/batch_calls")
+        tr.counters.inc("flush/batch_rows", nvm)
         demands = np.empty((nvm, vms[0].demand.shape[0]))
         bids = np.empty(nvm)
         pids = np.empty(nvm, dtype=np.int64)
@@ -162,11 +161,8 @@ class AllocationPolicy:
             demands[b] = vm.demand
             bids[b] = vm.bid
             pids[b] = vm.pool
-        if tr.enabled:
-            tr.begin("allocation", "flush/feasibility")
-        feas = pool.direct_mask_batch(demands, bids, pids)
-        if tr.enabled:
-            tr.end()
+        with tr.span("allocation", "flush/feasibility"):
+            feas = pool.direct_mask_batch(demands, bids, pids)
         any_row = feas.any(axis=1)
         for b in np.flatnonzero(any_row):
             return int(b), self._pick_direct(feas[b], vms[b], pool)
@@ -301,48 +297,40 @@ class HlemVmp(AllocationPolicy):
         if self.backend == "jax":
             free, spot_frac = pool.storage_views()
             tr = self.tracer
+            with tr.span("allocation", "pick/call"):
+                mirror = self._mirror
+                changed = (pool.rows_since(self._mirror_pos)
+                           if pool is self._mirror_pool else None)
+                self._mirror_pool, self._mirror_pos = pool, pool.track_rows()
+                pool.compact_row_log(self._mirror_pos)
+                ids = [] if changed is None else sorted(set(changed))
+                sent = uploads = 0
+                if (changed is None or mirror.rows != free.shape[0]
+                        or len(ids) > dirty_capacity(free.shape[0])):
+                    sent = mirror.upload(free, spot_frac)
+                    uploads, ids = 1, []
+                packed = pack_pick(mask, alpha, ids, free, spot_frac)
+                out = mirror.scores_tol(packed)
             if tr.enabled:
-                tr.begin("allocation", "pick/call")
-            mirror = self._mirror
-            changed = (pool.rows_since(self._mirror_pos)
-                       if pool is self._mirror_pool else None)
-            self._mirror_pool, self._mirror_pos = pool, pool.track_rows()
-            pool.compact_row_log(self._mirror_pos)
-            ids = [] if changed is None else sorted(set(changed))
-            sent = uploads = 0
-            if (changed is None or mirror.rows != free.shape[0]
-                    or len(ids) > dirty_capacity(free.shape[0])):
-                sent = mirror.upload(free, spot_frac)
-                uploads, ids = 1, []
-            packed = pack_pick(mask, alpha, ids, free, spot_frac)
-            out = mirror.scores_tol(packed)
-            if tr.enabled:
-                tr.end()
                 tr.counters.inc("pick/h2d_bytes", sent + device_arg_bytes(
                     mirror.free, mirror.spot_frac, packed))
                 tr.counters.inc("pick/dirty_rows", len(ids))
                 tr.counters.inc("pick/mirror_uploads", uploads)
             self.device_picks += 1
-            if tr.enabled:
-                tr.begin("allocation", "pick/readback")
-            out = np.asarray(out)
-            scores, tol = out[:-1], float(out[-1])
-            if tr.enabled:
-                tr.end()
+            with tr.span("allocation", "pick/readback"):
+                out = np.asarray(out)
+                scores, tol = out[:-1], float(out[-1])
             hid = certified_pick(scores, tol, free, spot_frac)
             if hid is not None:
                 return hid
             # a near-tie float32 cannot order: the exact pick decides
             self.device_fallbacks += 1
-            if tr.enabled:
-                tr.begin("allocation", "pick/host-exact")
-                tr.counters.inc("pick/host_exact_rows",
-                                int(np.count_nonzero(mask)))
-            hid = hlem_pick_np(pool.free(), mask, pool.spot_frac_view(),
-                               alpha)
-            if tr.enabled:
-                tr.end()
-            return hid
+            with tr.span("allocation", "pick/host-exact"):
+                if tr.enabled:
+                    tr.counters.inc("pick/host_exact_rows",
+                                    int(np.count_nonzero(mask)))
+                return hlem_pick_np(pool.free(), mask, pool.spot_frac_view(),
+                                    alpha)
         return hlem_pick_np(pool.free(), mask, pool.spot_frac_view(), alpha)
 
     def _pick_direct(self, mask, vm, pool):

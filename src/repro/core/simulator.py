@@ -48,6 +48,12 @@ from .types import (
 
 _EPS = 1e-9
 
+# span and counter names of each event kind, built once: the event loop
+# builds no string per event
+_DISPATCH_SPAN = {k: "dispatch/" + k.value for k in EventKind}
+_KIND_COUNTER = {k: "events/" + k.value for k in EventKind}
+_FLUSH_SPAN = {m: "flush/" + m for m in ("batched", "per_vm")}
+
 
 @dataclass
 class SimConfig:
@@ -111,24 +117,27 @@ class MarketSimulator:
         ordinary lifecycle listeners.  ``serve=None`` is bit-identical to a
         serve-less simulator.
 
-        ``obs`` — optional :class:`repro.obs.tracer.Tracer`.  When enabled,
-        the event loop runs a traced variant that records a span per
-        dispatch, per-kind/per-cause counters, and cadence counter
-        snapshots; subsystem tick phases add nested spans.  The tracer is
-        observation-only (no randomness, no state mutation), so metrics
-        are identical with or without it; ``obs=None`` selects the plain
-        untraced loop with zero added per-event work.
+        ``obs`` — optional :class:`repro.obs.tracer.Tracer`.  The event
+        loop records a span per dispatch, per-kind/per-cause counters, and
+        cadence counter snapshots; subsystem tick phases add nested spans.
+        The tracer is observation-only (no randomness, no state mutation),
+        so metrics are identical with or without it.  With ``obs=None`` the
+        loop runs on the inert ``NULL_TRACER``: a few no-op calls per
+        event, no clock read, no string built.
 
         ``events`` — optional :class:`repro.obs.eventlog.EventLog`: the
         structured flight recorder.  Every lifecycle and market transition
         emits one record (guarded by ``events.enabled`` — a single
         attribute load when off); like the tracer it is observation-only,
-        so logged and unlogged runs produce byte-identical metrics."""
+        so logged and unlogged runs produce byte-identical metrics.
+
+        :meth:`instrument` hands ``obs`` and ``events`` to every subsystem
+        given here."""
         self.policy = policy or FirstFit()
-        self.obs = obs if obs is not None else NULL_TRACER
-        self.events = events if events is not None else NULL_RECORDER
+        self.obs = NULL_TRACER
+        self.events = NULL_RECORDER
         self.config = config or SimConfig()
-        assert self.config.flush_mode in ("batched", "per_vm")
+        assert self.config.flush_mode in _FLUSH_SPAN
         self.pool = HostPool()
         self.engine = engine
         self.migration = migration
@@ -194,6 +203,25 @@ class MarketSimulator:
                                 EventKind.AUTOSCALE)
             self.on("vm_interrupted", serve.on_vm_interrupted)
             self.on("vm_finished", serve.on_vm_finished)
+        self.instrument(obs, events)
+
+    def instrument(self, obs=None, events=None) -> None:
+        """Hand a tracer and/or an event recorder to the simulator and to
+        every subsystem it holds, so spans nest and records land in one
+        place.  An argument left None changes nothing: a subsystem then
+        keeps whatever its caller attached."""
+        if obs is not None:
+            self.obs = obs
+            for part in (self.policy, self.engine, self.migration,
+                         self.fleet, self.serve):
+                if part is not None:
+                    part.tracer = obs
+        if events is not None:
+            self.events = events
+            for part in (self.engine, self.migration, self.fleet,
+                         self.faults, self.serve):
+                if part is not None:
+                    part.events = events
 
     def _arm_tick(self, t: float) -> None:
         """(Re)start the PRICE_TICK chain.  The chain stops itself when the
@@ -271,54 +299,31 @@ class MarketSimulator:
             # the chain stopped in a previous run (idle, or queued-only
             # state under an unbounded horizon); resume it for this run
             self._arm_tick(self.now)
-        if self.obs.enabled:
-            return self._run_traced(limit)
-        heappop = heapq.heappop
-        strict = self.config.strict_invariants
-        while heap and heap[0][0] <= limit:
-            ev = heappop(heap)[3]
-            self.now = ev.time
-            self._dispatch(ev)
-            if strict:
-                self.pool.check_invariants(self.now)
-        self.now = min(limit, self.now) if limit != float("inf") else self.now
-        return self.metrics
-
-    def _run_traced(self, limit: float) -> Metrics:
-        """Traced twin of the ``run`` hot loop: a ``dispatch/<kind>`` span
-        and per-kind counter per event, plus cadence counter snapshots.
-        Kept separate so the untraced loop carries zero added per-event
-        work — selecting the loop body happens once per ``run`` call."""
-        heap = self.queue._heap
         heappop = heapq.heappop
         strict = self.config.strict_invariants
         tr = self.obs
-        counters = tr.counters
-        inc = counters.inc
+        span = tr.span
+        inc = tr.counters.inc
+        due = tr.counters_due
         while heap and heap[0][0] <= limit:
             ev = heappop(heap)[3]
             t = ev.time
             self.now = t
-            kind_name = ev.kind.value
+            kind = ev.kind
             inc("events/total")
-            inc("events/" + kind_name)
-            tr.begin("event-loop", "dispatch/" + kind_name)
-            try:
+            inc(_KIND_COUNTER[kind])
+            # a handler that raises closes its open spans as aborted, so a
+            # truncated trace still exports as valid Chrome JSON
+            with span("event-loop", _DISPATCH_SPAN[kind], t):
                 self._dispatch(ev)
-            except BaseException:
-                # a handler (or a listener it called) raised mid-span:
-                # close every open span so the stack stays well-nested and
-                # the truncated trace still exports as valid Chrome JSON
-                tr.unwind(t)
-                raise
-            tr.end(t, None)
-            if tr.counters_due(t):
+            if due(t):
                 tr.snapshot(t, self._obs_gauges())
             if strict:
                 self.pool.check_invariants(self.now)
         self.now = min(limit, self.now) if limit != float("inf") else self.now
-        # closing snapshot so the counter timeseries always covers run end
-        tr.snapshot(self.now, self._obs_gauges())
+        if tr.enabled:
+            # closing snapshot so the counter timeseries covers run end
+            tr.snapshot(self.now, self._obs_gauges())
         return self.metrics
 
     def _obs_gauges(self) -> Dict[str, float]:
@@ -389,15 +394,11 @@ class MarketSimulator:
         self._record()
 
     def _try_allocate(self, vm: Vm, fresh: bool) -> bool:
-        tr = self.obs
-        if tr.enabled:
-            # one placement, the policy's picks inside it
-            tr.begin("allocation", "place")
-        hid, needs_clearing = self.policy.find_host(
-            vm, self.pool, self.now, allow_spot_clearing=True
-        )
-        if tr.enabled:
-            tr.end(self.now)
+        # one placement, the policy's picks inside it
+        with self.obs.span("allocation", "place", self.now):
+            hid, needs_clearing = self.policy.find_host(
+                vm, self.pool, self.now, allow_spot_clearing=True
+            )
         if hid < 0:
             self._enqueue_pending(vm, fresh, tested=True)
             return False
@@ -538,8 +539,7 @@ class MarketSimulator:
         self.metrics.interruption_events.append(
             InterruptionEvent(vm.id, self.now, vm.history[-1].host, kind,
                               cause))
-        if self.obs.enabled:
-            self.obs.counters.inc("interruptions/" + cause)
+        self.obs.counters.inc("interruptions/" + cause)
         if self.events.enabled:
             hid = vm.history[-1].host
             self.events.emit(self.now, "interrupt", vm=vm.id,
@@ -594,70 +594,57 @@ class MarketSimulator:
         t = self.now
         fi = self.faults
         tr = self.obs
-        traced = tr.enabled
         if fi is not None:
             # outage transitions first (the utilization signal must see the
             # downed hosts), then crunch/spike biases into the normal tick
-            if traced:
-                tr.begin("market-tick", "tick/faults")
-            self._fault_begin_tick(t)
-            if traced:
-                tr.end(t, None)
-                tr.begin("market-tick", "tick/engine")
-            prices = eng.tick(self.pool, t, util_bias=fi.util_bias(t),
-                              shock_bias=fi.shock_bias(t))
-        else:
-            if traced:
-                tr.begin("market-tick", "tick/engine")
-            prices = eng.tick(self.pool, t)
-        if traced:
-            tr.end(t, None)
-            tr.begin("market-tick", "tick/wave")
-        self.pool.set_pool_prices(prices)
-        m = self.metrics
-        m.price_series.extend(
-            (t, pid, float(p)) for pid, p in enumerate(prices))
-        victims, vpools = self.pool.market_victims(prices, t)
-        if victims.size:
-            counts = np.bincount(vpools, minlength=eng.n_pools)
-            evl = self.events
-            for pid in np.flatnonzero(counts):
-                m.wave_events.append(
-                    WaveEvent(t, int(pid), float(prices[pid]),
-                              int(counts[pid])))
-                if evl.enabled:
-                    evl.emit(t, "wave", pool=int(pid),
-                             a=float(prices[pid]), b=float(counts[pid]))
-            if traced:
+            with tr.span("market-tick", "tick/faults", t):
+                self._fault_begin_tick(t)
+        with tr.span("market-tick", "tick/engine", t):
+            if fi is None:
+                prices = eng.tick(self.pool, t)
+            else:
+                prices = eng.tick(self.pool, t, util_bias=fi.util_bias(t),
+                                  shock_bias=fi.shock_bias(t))
+        with tr.span("market-tick", "tick/wave", t) as wave:
+            self.pool.set_pool_prices(prices)
+            m = self.metrics
+            m.price_series.extend(
+                (t, pid, float(p)) for pid, p in enumerate(prices))
+            victims, vpools = self.pool.market_victims(prices, t)
+            wave.args = {"victims": int(victims.size)}
+            if victims.size:
+                counts = np.bincount(vpools, minlength=eng.n_pools)
+                evl = self.events
+                for pid in np.flatnonzero(counts):
+                    m.wave_events.append(
+                        WaveEvent(t, int(pid), float(prices[pid]),
+                                  int(counts[pid])))
+                    if evl.enabled:
+                        evl.emit(t, "wave", pool=int(pid),
+                                 a=float(prices[pid]), b=float(counts[pid]))
                 tr.counters.inc("waves")
                 tr.counters.inc("wave_victims", int(victims.size))
                 tr.instant("market-tick", "wave", t,
                            {"victims": int(victims.size)})
-            w = self.config.warning_time
-            if w > 0:
-                vids = [int(v) for v in victims]
-                for vid in vids:
-                    v = self.vms[vid]
-                    self._set_state(v, VmState.INTERRUPTING)
-                    self.pool.mark_uninterruptible(v)
-                self.queue.push(t + w, EventKind.INTERRUPT_COMMIT,
-                                ("wave", vids))
-            else:
-                for vid in victims:
-                    v = self.vms[int(vid)]
-                    self._interrupt(v, kind=v.behavior.value,
-                                    cause=InterruptionCause.PRICE_WAVE)
-        if traced:
-            tr.end(t, {"victims": int(victims.size)})
+                w = self.config.warning_time
+                if w > 0:
+                    vids = [int(v) for v in victims]
+                    for vid in vids:
+                        v = self.vms[vid]
+                        self._set_state(v, VmState.INTERRUPTING)
+                        self.pool.mark_uninterruptible(v)
+                    self.queue.push(t + w, EventKind.INTERRUPT_COMMIT,
+                                    ("wave", vids))
+                else:
+                    for vid in victims:
+                        v = self.vms[int(vid)]
+                        self._interrupt(v, kind=v.behavior.value,
+                                        cause=InterruptionCause.PRICE_WAVE)
         # injected interruption storms land after the ordinary wave — the
         # wave already reclaimed below-bid VMs, the storm takes its share of
         # whoever is left running
         if fi is not None and self._storms_due:
-            if traced:
-                tr.begin("market-tick", "tick/storms")
-                self._fault_apply_storms()
-                tr.end(t, None)
-            else:
+            with tr.span("market-tick", "tick/storms", t):
                 self._fault_apply_storms()
         # capacity freed by the wave (and any price drops, via the gain log)
         # feeds straight back into the queue — victims can land in a cheaper
@@ -667,22 +654,14 @@ class MarketSimulator:
         # post-flush state and emits MIGRATE_START events at this timestamp
         # (processed after same-time submissions; each start re-validates)
         if self.migration is not None:
-            if traced:
-                tr.begin("market-tick", "tick/migration")
-                self._plan_migrations()
-                tr.end(t, None)
-            else:
+            with tr.span("market-tick", "tick/migration", t):
                 self._plan_migrations()
         # the fleet manager observes the settled post-wave, post-flush,
         # post-planner state: sample capacity, replace dead slots (its
         # submissions are VM_SUBMIT events at this timestamp, processed
         # after the tick by event priority)
         if self.fleet is not None:
-            if traced:
-                tr.begin("market-tick", "tick/fleet")
-                self.fleet.on_tick(self, t)
-                tr.end(t, None)
-            else:
+            with tr.span("market-tick", "tick/fleet", t):
                 self.fleet.on_tick(self, t)
         self._record()
         # keep ticking while any event or live VM remains (the chain is the
@@ -720,12 +699,7 @@ class MarketSimulator:
         if sv is None:
             return
         t = self.now
-        tr = self.obs
-        if tr.enabled:
-            tr.begin("serve", "tick/serve")
-            sv.on_tick(self, t)
-            tr.end(t, None)
-        else:
+        with self.obs.span("serve", "tick/serve", t):
             sv.on_tick(self, t)
         if self._serve_rearm():
             self.queue.push(t + sv.config.tick, EventKind.SERVE_TICK)
@@ -735,12 +709,7 @@ class MarketSimulator:
         if sv is None or sv.autoscaler is None:
             return
         t = self.now
-        tr = self.obs
-        if tr.enabled:
-            tr.begin("serve", "tick/autoscale")
-            sv.on_autoscale(self, t)
-            tr.end(t, None)
-        else:
+        with self.obs.span("serve", "tick/autoscale", t):
             sv.on_autoscale(self, t)
         if self._serve_rearm():
             self.queue.push(t + sv.autoscaler.config.cadence,
@@ -773,14 +742,11 @@ class MarketSimulator:
         vm = self.vms[vid]
         if gen != vm.generation or vm.state is not VmState.RUNNING:
             return  # finished / interrupted / preempt-warned since planning
-        tr = self.obs
-        if tr.enabled:
-            # the destination's placement, as in _try_allocate
-            tr.begin("allocation", "place")
-        mask = self.pool.direct_mask_into(vm.demand, vm.bid, dst_pool)
-        hid = self.policy._pick_direct(mask, vm, self.pool) if mask.any() else -1
-        if tr.enabled:
-            tr.end(self.now)
+        # the destination's placement, as in _try_allocate
+        with self.obs.span("allocation", "place", self.now):
+            mask = self.pool.direct_mask_into(vm.demand, vm.bid, dst_pool)
+            hid = (self.policy._pick_direct(mask, vm, self.pool)
+                   if mask.any() else -1)
         if hid < 0:
             # no single host fits (pool-aggregate headroom was fragmented,
             # or same-time submissions took it): stay put, and black the VM
@@ -803,8 +769,7 @@ class MarketSimulator:
         self._migrating[vid] = mev
         self.metrics.migration_events.append(mev)
         self.metrics.migrations_started += 1
-        if self.obs.enabled:
-            self.obs.counters.inc("migrations/started")
+        self.obs.counters.inc("migrations/started")
         if self.events.enabled:
             # pool/host name the *source* (the departure side — occupancy
             # analytics key on it); the destination pool rides in b and the
@@ -850,8 +815,7 @@ class MarketSimulator:
                             vm.id, vm.generation)
             self.metrics.migrations_completed += 1
             self.metrics.migration_downtime += self.now - mev.t_start
-            if self.obs.enabled:
-                self.obs.counters.inc("migrations/completed")
+            self.obs.counters.inc("migrations/completed")
             if self.events.enabled:
                 self.events.emit(self.now, "migrate-complete", vm=vm.id,
                                  pool=int(mev.dst_pool), host=hid,
@@ -871,10 +835,9 @@ class MarketSimulator:
             self.metrics.interruption_events.append(
                 InterruptionEvent(vid, self.now, vm.history[-1].host, kind,
                                   cause=InterruptionCause.MIGRATION_FAILED))
-            if self.obs.enabled:
-                self.obs.counters.inc(
-                    "interruptions/" + InterruptionCause.MIGRATION_FAILED)
-                self.obs.counters.inc("migrations/failed")
+            self.obs.counters.inc(
+                "interruptions/" + InterruptionCause.MIGRATION_FAILED)
+            self.obs.counters.inc("migrations/failed")
             if self.events.enabled:
                 self.events.emit(self.now, "migrate-complete", vm=vm.id,
                                  pool=int(mev.dst_pool), host=hid,
@@ -969,8 +932,7 @@ class MarketSimulator:
                 self.metrics.interruption_events.append(
                     InterruptionEvent(v.id, self.now, hid,
                                       InterruptionCause.HOST_REMOVED, cause))
-                if self.obs.enabled:
-                    self.obs.counters.inc("interruptions/" + cause)
+                self.obs.counters.inc("interruptions/" + cause)
                 if self.events.enabled:
                     self.events.emit(
                         self.now, "interrupt", vm=v.id,
@@ -1033,27 +995,17 @@ class MarketSimulator:
     # --------------------------------------------------------- resubmission
     def _flush_pending(self) -> None:
         """Resubmission pass: try to place queued requests (§V-D)."""
-        tr = self.obs
-        evl = self.events
-        if not (tr.enabled or evl.enabled):
-            if self.config.flush_mode == "per_vm":
+        mode = self.config.flush_mode
+        before = self.metrics.allocations
+        with self.obs.span("allocation", _FLUSH_SPAN[mode], self.now) as sp:
+            if mode == "per_vm":
                 self._flush_pending_per_vm()
             else:
                 self._flush_pending_batched()
-            return
-        mode = self.config.flush_mode
-        before = self.metrics.allocations
-        if tr.enabled:
-            tr.begin("allocation", "flush/" + mode)
-        if mode == "per_vm":
-            self._flush_pending_per_vm()
-        else:
-            self._flush_pending_batched()
-        placed = self.metrics.allocations - before
-        if tr.enabled:
-            tr.end(self.now, {"placed": placed})
-        if evl.enabled:
-            evl.emit(self.now, "alloc-flush", a=float(placed))
+            placed = self.metrics.allocations - before
+            sp.args = {"placed": placed}
+        if self.events.enabled:
+            self.events.emit(self.now, "alloc-flush", a=float(placed))
 
     def _queues(self) -> Dict[str, Dict[int, Vm]]:
         return {
@@ -1140,34 +1092,31 @@ class MarketSimulator:
         retry, log = self._retry_pos, pool.gain_log
         fits = pool.fits_fast
         n_pending = len(pending)
-        tr = self.obs
+        span = self.obs.span
         while i < n_pending:
-            if tr.enabled:
-                tr.begin("allocation", "flush/memo")
             # memo filter: keep only VMs that might fit under current state —
             # a VM that failed its last full test can only have become
             # feasible on a host whose free capacity increased since then.
             # Positions are absolute (base counts compacted-away entries).
-            base = pool._gain_base
-            glen = base + len(log)
-            check: List[int] = []
-            for j in range(i, n_pending):
-                vm = pending[j][1]
-                pos = retry.get(vm.id)
-                if pos is not None:
-                    if pos >= glen:
-                        continue  # nothing gained since the last failure
-                    hit = False
-                    for h in log[max(pos - base, 0):]:
-                        if fits(h, vm.demand):
-                            hit = True
-                            break
-                    if not hit:
-                        retry[vm.id] = glen
-                        continue
-                check.append(j)
-            if tr.enabled:
-                tr.end(self.now)
+            with span("allocation", "flush/memo", self.now):
+                base = pool._gain_base
+                glen = base + len(log)
+                check: List[int] = []
+                for j in range(i, n_pending):
+                    vm = pending[j][1]
+                    pos = retry.get(vm.id)
+                    if pos is not None:
+                        if pos >= glen:
+                            continue  # nothing gained since the last failure
+                        hit = False
+                        for h in log[max(pos - base, 0):]:
+                            if fits(h, vm.demand):
+                                hit = True
+                                break
+                        if not hit:
+                            retry[vm.id] = glen
+                            continue
+                    check.append(j)
             if not check:
                 break
             # one feasibility matrix decides which VM places (a VM places iff

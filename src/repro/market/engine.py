@@ -83,9 +83,8 @@ class MarketEngine:
 
     def __init__(self, config: MarketConfig):
         self.config = config
-        #: telemetry hooks (``repro.obs``); the build layer swaps in the
-        #: live tracer / event recorder, instrumentation guards on
-        #: ``tracer.enabled`` / ``events.enabled``
+        #: telemetry hooks (``repro.obs``); the simulator hands in its
+        #: live tracer / event recorder
         self.tracer = NULL_TRACER
         self.events = NULL_RECORDER
         self.n_pools = len(config.pools)
@@ -233,28 +232,23 @@ class MarketEngine:
         self._ts_buf[k] = now
         if self._groups is None:
             self._build_groups()
-        tr = self.tracer
-        traced = tr.enabled
-        if traced:
-            tr.begin("market-engine",
-                     "engine/families" if self.use_vectorized
-                     else "engine/scalar-walk")
-        if self.use_vectorized:
-            for g in self._groups:
-                fam, idx, state = g
-                state, p = fam.step(state, util[idx], z[idx])
-                g[2] = state
-                self.prices[idx] = p
-        else:
-            # scalar oracle walk: identical shocks, identical kernels
-            for i, proc in enumerate(self.processes):
-                if getattr(proc, "shock_protocol", False):
-                    p = proc.price(float(util[i]), shock=float(z[i]))
-                else:
-                    p = proc.price(float(util[i]))
-                self.prices[i] = p
-        if traced:
-            tr.end(now, None)
+        with self.tracer.span("market-engine",
+                              "engine/families" if self.use_vectorized
+                              else "engine/scalar-walk", now):
+            if self.use_vectorized:
+                for g in self._groups:
+                    fam, idx, state = g
+                    state, p = fam.step(state, util[idx], z[idx])
+                    g[2] = state
+                    self.prices[idx] = p
+            else:
+                # scalar oracle walk: identical shocks, identical kernels
+                for i, proc in enumerate(self.processes):
+                    if getattr(proc, "shock_protocol", False):
+                        p = proc.price(float(util[i]), shock=float(z[i]))
+                    else:
+                        p = proc.price(float(util[i]))
+                    self.prices[i] = p
         self._ph_buf[:, k] = self.prices
         self._n_ticks = k + 1
         if self.events.enabled:

@@ -102,7 +102,7 @@ class FaultInjector:
     flags) — use a fresh injector per simulation, like the engine."""
 
     #: event recorder — fault activations feed the flight log
-    events_log = NULL_RECORDER
+    events = NULL_RECORDER
 
     def __init__(self, events: Sequence[FaultEvent], n_pools: int):
         evs = []
@@ -118,10 +118,11 @@ class FaultInjector:
         # deterministic schedule order regardless of generator order
         evs.sort(key=lambda e: (e.t0, FAULT_KINDS.index(e.kind),
                                 e.pools or (), e.magnitude))
-        self.events: Tuple[FaultEvent, ...] = tuple(evs)
+        #: the fault schedule, in firing order
+        self.schedule: Tuple[FaultEvent, ...] = tuple(evs)
         self.n_pools = int(n_pools)
-        self._started = [False] * len(self.events)
-        self._ended = [False] * len(self.events)
+        self._started = [False] * len(self.schedule)
+        self._ended = [False] * len(self.schedule)
 
     # ------------------------------------------------------------- tick API
     def _pool_ids(self, ev: FaultEvent) -> Tuple[int, ...]:
@@ -136,13 +137,13 @@ class FaultInjector:
         indices of ``pool-outage`` events newly *ending* (reactivate)."""
         started: List[Tuple[int, FaultEvent]] = []
         ended: List[int] = []
-        for i, ev in enumerate(self.events):
+        for i, ev in enumerate(self.schedule):
             if not self._started[i] and ev.t0 <= now + _EPS:
                 self._started[i] = True
                 started.append((i, ev))
-                if self.events_log.enabled:
+                if self.events.enabled:
                     for p in self._pool_ids(ev):
-                        self.events_log.emit(
+                        self.events.emit(
                             now, "fault", pool=int(p),
                             a=float(ev.magnitude), b=float(ev.t1),
                             aux=ev.kind)
@@ -155,7 +156,7 @@ class FaultInjector:
 
     def _bias(self, now: float, kind: str) -> Optional[np.ndarray]:
         out = None
-        for ev in self.events:
+        for ev in self.schedule:
             if ev.kind != kind:
                 continue
             if ev.t0 <= now + _EPS < ev.t1 + _EPS and now < ev.t1 - _EPS:

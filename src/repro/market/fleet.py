@@ -395,7 +395,7 @@ class FleetManager:
     fleet's whole job.  Stateful across one run; use a fresh manager per
     simulation, like the engine."""
 
-    #: telemetry hook (``repro.obs``); the build layer swaps in the live
+    #: telemetry hook (``repro.obs``); the simulator hands in its live
     #: tracer — rung hits and launches feed the counter registry
     tracer = NULL_TRACER
     #: event recorder — rung/launch/retire records for the flight log
@@ -623,8 +623,7 @@ class FleetManager:
             for s, p in zip(fresh, targets):
                 m.fallback_counts["launch"] = (
                     m.fallback_counts.get("launch", 0) + 1)
-                if self.tracer.enabled:
-                    self.tracer.counters.inc("fleet/rung/launch")
+                self.tracer.counters.inc("fleet/rung/launch")
                 if self.events.enabled:
                     self.events.emit(now, "fleet-rung", pool=int(p),
                                      a=float(s), aux="launch")
@@ -653,10 +652,8 @@ class FleetManager:
         m = sim.metrics
         rung = self._ladder[int(self.slot_rung[s])][0]
         m.fallback_counts[rung] = m.fallback_counts.get(rung, 0) + 1
-        if self.tracer.enabled:
-            self.tracer.counters.inc("fleet/rung/" + rung)
-            self.tracer.instant("fleet", "rung/" + rung, now,
-                                {"slot": int(s)})
+        self.tracer.counters.inc("fleet/rung/" + rung)
+        self.tracer.instant("fleet", "rung/" + rung, now, {"slot": int(s)})
         if self.events.enabled:
             self.events.emit(now, "fleet-rung", a=float(s), aux=rung)
         if rung == "scale-down":

@@ -127,7 +127,7 @@ class MigrationPlan:
 class MigrationPlanner:
     """Scores the market registry each tick and emits batched plans."""
 
-    #: telemetry hooks (``repro.obs``); the build layer swaps in the live
+    #: telemetry hooks (``repro.obs``); the simulator hands in its live
     #: tracer/recorder — class attributes so planner construction stays
     #: untouched
     tracer = NULL_TRACER
@@ -157,15 +157,11 @@ class MigrationPlanner:
         slope, so the planner's own herd prices itself out of a destination
         before it can spike it.  Fully deterministic, no RNG."""
         tr = self.tracer
-        if not (tr.enabled or self.events.enabled):
-            return self._plan_impl(host_pool, engine, now, inflight_per_pool)
-        if tr.enabled:
-            tr.begin("migration", "plan/" + self.config.policy)
-        plans = self._plan_impl(host_pool, engine, now, inflight_per_pool)
-        if tr.enabled:
+        with tr.span("migration", "plan/" + self.config.policy, now) as sp:
+            plans = self._plan_impl(host_pool, engine, now, inflight_per_pool)
             if plans:
                 tr.counters.inc("migrations/planned", len(plans))
-            tr.end(now, {"plans": len(plans)})
+            sp.args = {"plans": len(plans)}
         if self.events.enabled:
             for p in plans:
                 self.events.emit(now, "migrate-plan", vm=p.vm_id,

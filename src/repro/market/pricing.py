@@ -76,52 +76,49 @@ def realized_cost_stats(vms: Iterable[Vm], engine, host_pool,
     order as the historical per-VM walk.
     """
     model = model or PriceModel()
-    tr = engine.tracer
-    if tr.enabled:
-        tr.begin("billing", "realized_cost")
-    total = od_equiv = wasted = spot_cost = 0.0
-    pool_of = host_pool.pool_of
-    vm_list = list(vms)
-    # gather every closed spot execution interval for one batched call
-    pids: list = []
-    t0s: list = []
-    t1s: list = []
-    caps: list = []
-    for vm in vm_list:
-        if vm.vm_type is not VmType.SPOT:
-            continue
-        for itv in vm.history:
-            if itv.stop is None:
+    # a post-run call: its span takes the last tick's time, not a live clock
+    last_tick = float(engine.tick_times()[-1]) if engine.n_ticks else 0.0
+    with engine.tracer.span("billing", "realized_cost", last_tick) as sp:
+        total = od_equiv = wasted = spot_cost = 0.0
+        pool_of = host_pool.pool_of
+        vm_list = list(vms)
+        # gather every closed spot execution interval for one batched call
+        pids: list = []
+        t0s: list = []
+        t1s: list = []
+        caps: list = []
+        for vm in vm_list:
+            if vm.vm_type is not VmType.SPOT:
                 continue
-            pids.append(int(pool_of[itv.host]))
-            t0s.append(itv.start)
-            t1s.append(itv.stop)
-            caps.append(vm.bid)
-    discounts = engine.discount_integrals(
-        np.asarray(pids, dtype=np.int64), np.asarray(t0s),
-        np.asarray(t1s), np.asarray(caps))
-    cursor = 0
-    for vm in vm_list:
-        rate = model.rate(vm.demand)
-        od_c = model.vm_od_equivalent(vm)
-        od_equiv += od_c
-        if vm.vm_type is not VmType.SPOT:
-            total += od_c
-            continue
-        c = 0.0
-        for itv in vm.history:
-            if itv.stop is None:
+            for itv in vm.history:
+                if itv.stop is None:
+                    continue
+                pids.append(int(pool_of[itv.host]))
+                t0s.append(itv.start)
+                t1s.append(itv.stop)
+                caps.append(vm.bid)
+        discounts = engine.discount_integrals(
+            np.asarray(pids, dtype=np.int64), np.asarray(t0s),
+            np.asarray(t1s), np.asarray(caps))
+        cursor = 0
+        for vm in vm_list:
+            rate = model.rate(vm.demand)
+            od_c = model.vm_od_equivalent(vm)
+            od_equiv += od_c
+            if vm.vm_type is not VmType.SPOT:
+                total += od_c
                 continue
-            c += rate / 3600.0 * float(discounts[cursor])
-            cursor += 1
-        total += c
-        spot_cost += c
-        if vm.state is VmState.TERMINATED:
-            wasted += c
-    if tr.enabled:
-        # post-run call: stamp with the last tick time, not a live clock
-        sim_t = float(engine.tick_times()[-1]) if engine.n_ticks else 0.0
-        tr.end(sim_t, {"intervals": len(pids)})
+            c = 0.0
+            for itv in vm.history:
+                if itv.stop is None:
+                    continue
+                c += rate / 3600.0 * float(discounts[cursor])
+                cursor += 1
+            total += c
+            spot_cost += c
+            if vm.state is VmState.TERMINATED:
+                wasted += c
+        sp.args = {"intervals": len(pids)}
     return {
         "cost": total,
         "od_equivalent": od_equiv,

@@ -213,17 +213,6 @@ def simulate_trace(
         engine=engine, migration=migration, rebid=rebid, obs=obs,
         events=events,
     )
-    if obs is not None and obs.enabled:
-        sim.policy.tracer = obs
-        if engine is not None:
-            engine.tracer = obs
-        if migration is not None:
-            migration.tracer = obs
-    if events is not None and events.enabled:
-        if engine is not None:
-            engine.events = events
-        if migration is not None:
-            migration.events = events
     wire_trace(sim, tr, cfg)
     metrics = sim.run(until=until)
     return sim, metrics

@@ -15,14 +15,16 @@ kinds, all produced by one :class:`Tracer`:
   sampled gauges (queue depth, registry size), snapshotted into a
   timeseries on a configurable sim-time cadence.
 
-Overhead contract: the disabled path must cost (almost) nothing.  Every
-instrumentation site in the engine guards on ``tracer.enabled`` — a single
-attribute load + branch — and the simulator's hot event loop selects an
-entirely *untraced* loop body when observability is off, so a disabled run
-executes byte-for-byte the same per-event code as a build with no tracer
-at all (regression-tested: metrics JSON equality, ``tests/obs``).  The
-:data:`NULL_TRACER` singleton is the default everywhere; sites never need
-a ``None`` check.
+Overhead contract: the disabled path must cost (almost) nothing.  A site
+wraps its work in ``with tracer.span(cat, name, sim_t):``; the
+:data:`NULL_TRACER` singleton, the default everywhere, hands back one
+shared span whose ``__enter__`` and ``__exit__`` do nothing, and its
+counters drop every update — two no-op calls, no clock read, no dict
+write.  A site keeps an ``if tracer.enabled:`` guard only where building
+an argument costs work (a ``count_nonzero``, a byte count, gauges).
+Sites never need a ``None`` check.  A disabled run computes exactly what
+a build with no tracer at all computes (regression-tested: metrics JSON
+equality, ``tests/obs``).
 
 Profiler mirror: while a :class:`Tracer` is enabled, every ``begin`` also
 opens a ``jax.profiler.TraceAnnotation`` named ``repro/<cat>/<name>``, and
@@ -80,18 +82,82 @@ class Counters:
         return snap
 
 
+class _NullCounters(Counters):
+    """The counters of a disabled tracer: every update is dropped."""
+
+    __slots__ = ()
+
+    def inc(self, key: str, n: int = 1) -> None:
+        pass
+
+    def set(self, key: str, value: float) -> None:
+        pass
+
+
+class _NullSpan:
+    """The one span a disabled tracer hands out: entering, leaving and
+    setting ``args`` or ``sim_t`` do nothing."""
+
+    __slots__ = ()
+    args = None
+    sim_t = None
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def __setattr__(self, key: str, value: Any) -> None:
+        pass
+
+
+_NULL_SPAN = _NullSpan()
+
+
+class _Span:
+    """A :class:`Tracer` span as a context manager: ``begin`` on entry,
+    ``end(sim_t, args)`` on exit.  The site may set ``args`` (and a late
+    ``sim_t``) on the object before it exits; an exception closes the span
+    with ``{"aborted": True}``, as :meth:`Tracer.unwind` would."""
+
+    __slots__ = ("tracer", "cat", "name", "sim_t", "args")
+
+    def __init__(self, tracer: "Tracer", cat: str, name: str,
+                 sim_t: Optional[float]) -> None:
+        self.tracer = tracer
+        self.cat = cat
+        self.name = name
+        self.sim_t = sim_t
+        self.args: Optional[dict] = None
+
+    def __enter__(self) -> "_Span":
+        self.tracer.begin(self.cat, self.name)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.tracer.end(self.sim_t, self.args if exc_type is None
+                        else {"aborted": True})
+        return False
+
+
 class NullTracer:
     """Inert tracer: ``enabled`` is False and every method is a no-op.
 
     Instrumentation sites hold a reference to this singleton by default, so
-    the fast-path check is one attribute load (``tr.enabled``) with no
-    ``None`` branch.  Kept deliberately method-complete: code may call any
-    tracer method without checking ``enabled`` first on cold paths.
+    they call it unguarded with no ``None`` branch: :meth:`span` returns a
+    shared no-op span and the counters drop every update.  Kept
+    method-complete: code may call any tracer method without checking
+    ``enabled`` first.
     """
 
     enabled = False
-    counters = Counters()          # shared sink; never snapshotted
+    counters = _NullCounters()
     on_snapshot: Optional[Callable] = None
+
+    def span(self, cat: str, name: str,
+             sim_t: Optional[float] = None) -> _NullSpan:
+        return _NULL_SPAN
 
     def begin(self, cat: str, name: str) -> None:
         pass
@@ -175,6 +241,12 @@ class Tracer:
         self._next_snap = 0.0 if counters_every is not None else None
 
     # ------------------------------------------------------------- spans
+    def span(self, cat: str, name: str,
+             sim_t: Optional[float] = None) -> _Span:
+        """``with tracer.span(cat, name, sim_t) as sp:`` — one span around
+        the block; ``sim_t=None`` as in :meth:`end`."""
+        return _Span(self, cat, name, sim_t)
+
     def begin(self, cat: str, name: str) -> None:
         note = None
         if self._annotate is not None:

@@ -108,7 +108,7 @@ class ServeManager:
     Stateful across one run; use a fresh manager per simulation, like the
     engine and the fleet manager."""
 
-    #: telemetry hook (``repro.obs``); the build layer swaps in the live
+    #: telemetry hook (``repro.obs``); the simulator hands in its live
     #: tracer — an autoscaler action marks an instant (the request counts
     #: are in the metrics)
     tracer = NULL_TRACER
@@ -270,9 +270,8 @@ class ServeManager:
             self.events.emit(now, "autoscale", a=float(new), b=float(old),
                              aux=self.autoscaler.policy_name)
         if decided is not None:
-            if self.tracer.enabled:
-                self.tracer.instant("serve", "autoscale", now,
-                                    {"from": old, "to": new})
+            self.tracer.instant("serve", "autoscale", now,
+                                {"from": old, "to": new})
             sim.fleet.set_target_units(sim, new, now)
 
     # ------------------------------------------------- lifecycle listeners

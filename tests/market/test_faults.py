@@ -84,7 +84,7 @@ def test_injector_sorts_schedule_and_coerces_dicts():
     fi = FaultInjector(
         [{"kind": "storm", "t0": 500.0, "magnitude": 0.5},
          FaultEvent("pool-outage", 100.0, 60.0, (1,))], n_pools=2)
-    assert [e.kind for e in fi.events] == ["pool-outage", "storm"]
+    assert [e.kind for e in fi.schedule] == ["pool-outage", "storm"]
     assert fi.pending()
     started, ended = fi.begin_tick(100.0)
     assert [e.kind for _, e in started] == ["pool-outage"]
@@ -133,18 +133,18 @@ def test_storm_victims_lowest_bids_first():
 def test_builtin_scenarios_compile_and_random_storms_are_seeded():
     for name in ("storm", "pool-outage", "price-spike", "capacity-crunch"):
         fi = make_fault_injector(name, 4, 14400.0, 60.0, 0)
-        assert fi.events and all(e.kind in name or e.kind == "storm"
-                                 for e in fi.events)
+        assert fi.schedule and all(e.kind in name or e.kind == "storm"
+                                   for e in fi.schedule)
     scripted = make_fault_injector(
         "scripted", 4, 14400.0, 60.0, 0,
         events=[{"kind": "storm", "t0": 120.0, "magnitude": 0.5},
                 FaultEvent("pool-outage", 60.0, 30.0, (1,))])
-    assert [e.kind for e in scripted.events] == ["pool-outage", "storm"]
+    assert [e.kind for e in scripted.schedule] == ["pool-outage", "storm"]
     a = make_fault_injector("random-storms", 4, 14400.0, 60.0, seed=3)
     b = make_fault_injector("random-storms", 4, 14400.0, 60.0, seed=3)
     c = make_fault_injector("random-storms", 4, 14400.0, 60.0, seed=4)
-    assert a.events == b.events            # pre-drawn schedule is seeded
-    assert a.events != c.events
+    assert a.schedule == b.schedule        # pre-drawn schedule is seeded
+    assert a.schedule != c.schedule
     with pytest.raises(ValueError, match="unknown fault scenario"):
         make_fault_injector("meteor-shower", 4, 14400.0, 60.0, 0)
 

@@ -38,15 +38,7 @@ def _market_spec(**overrides):
 
 def _attach(sim, log):
     """Swap a custom (e.g. windowed) recorder into every emit site."""
-    sim.events = log
-    if sim.engine is not None:
-        sim.engine.events = log
-    if sim.migration is not None:
-        sim.migration.events = log
-    if sim.fleet is not None:
-        sim.fleet.events = log
-    if sim.faults is not None:
-        sim.faults.events_log = log
+    sim.instrument(events=log)
 
 
 def _flip_one_bid(sim, after=300.0):

@@ -1,8 +1,8 @@
 """Event flight recorder: columnar log, round-trips, validation, and the
 observation-only invariant — at fixed (spec, seed) the metrics row must be
 byte-identical whether the run records the event log or not, and a log-off
-run must still take the plain untraced loop (the PR 7 overhead contract
-extended to ISSUE 8's recorder)."""
+run must keep the inert recorder (the PR 7 overhead contract extended to
+ISSUE 8's recorder)."""
 import json
 
 import numpy as np
@@ -147,8 +147,8 @@ def test_fleet_faults_identity():
 
 
 def test_events_only_spec_keeps_plain_loop():
-    # events alone must NOT build a tracer: the simulator keeps NULL_TRACER
-    # and run() takes the plain untraced loop — recording rides inside the
+    # events alone must NOT build a tracer: the simulator keeps NULL_TRACER,
+    # whose spans and counters do nothing — recording rides inside the
     # ordinary handlers
     sim = build(RunSpec(**_market_kwargs(), obs=EVENTS_ON), 0)
     assert sim.obs.enabled is False

@@ -66,7 +66,7 @@ def test_fleet_faults_identity():
 
 def test_off_spec_builds_plain_untraced_loop():
     # ObsSpec with everything off must not even construct a tracer: the
-    # simulator gets NULL_TRACER and run() takes the plain loop
+    # simulator gets NULL_TRACER, whose spans and counters do nothing
     sim = build(RunSpec(**_market_kwargs(), obs=ObsSpec()), 0)
     assert sim.obs.enabled is False
     sim_on = build(RunSpec(**_market_kwargs(), obs=OBS_ON), 0)
