@@ -246,6 +246,18 @@ def _candidate_weights_np(free, idx, flat_exit: bool,
 #: (``tools/host_pick_bench.py`` times both on the host it runs on).
 PICK_NP_M_CUTOVER = 96
 
+#: the most consecutive arrivals one device call places: the event loop
+#: hands :class:`~repro.core.allocation.HlemVmp` runs of up to this many
+#: submissions, and :func:`hlem_scores_tol_jax_run` places them one after
+#: the other on the device.  A call costs a round trip whose fixed part
+#: dominates, plus a mask byte and a score word a row each step: on a TPU
+#: v5e chip at 16,384 rows (``tools/pick_roundtrip.py --way run``) one
+#: step took 1.39 ms, 16 steps 179 us a step and 32 steps 138.  The
+#: paper-scale trace's arrivals come 5.1-7.8 to a call cut at 16 and
+#: 5.3-8.8 at 32 (finishes end most runs first), so 32 buys little and
+#: fetches twice the lines.
+PICK_RUN_K = 16
+
 #: fleet-size crossover for the batched numpy scorer: above this many hosts
 #: the (B, n, D) broadcast core loses to a compressed per-row pass (its
 #: masked intermediates thrash cache, while the per-row path reduces over the
@@ -333,6 +345,13 @@ DEVICE_TIE_TOL = 1e-5
 
 
 def _scores_and_tol(free, mask, spot_frac, alpha):
+    return _scores_tol_terms(free, mask, spot_frac, alpha)[:2]
+
+
+def _scores_tol_terms(free, mask, spot_frac, alpha):
+    """(scores, tol, w, span, mag): the masked scores and their bound, and
+    the weights, spans and largest candidate magnitudes ``tol`` was made
+    from (per dimension)."""
     free = free.astype(jnp.float32)
     maskf = mask.astype(jnp.float32)[:, None]          # (n,1)
     m = jnp.sum(maskf)                                 # candidate count
@@ -372,7 +391,7 @@ def _scores_and_tol(free, mask, spot_frac, alpha):
                                  w * mag / jnp.where(degen, 1.0, span)))
     tol = (DEVICE_TIE_TOL * (1.0 + 1.0 / jnp.maximum(gsum, 1e-30))
            + 2.0 ** -20 * rounding)
-    return jnp.where(mask, hs, -big), tol
+    return jnp.where(mask, hs, -big), tol, w, span, mag
 
 
 @jax.jit
@@ -422,13 +441,43 @@ def pack_pick(mask: np.ndarray, alpha: float, ids, free: np.ndarray,
     buf[: mask.size] = mask
     words = buf[head:].view("<u4")
     words[0] = np.float32(alpha).view(np.uint32)
-    k = len(ids)
-    words[1: 1 + k] = ids
-    words[1 + k: 1 + k_cap] = rows
-    vals = buf[head + 4 * (1 + k_cap):].view("<f4").reshape(k_cap, 2 * d)
+    _put_rows(words[1:], ids, free, spot_frac)
+    return buf
+
+
+def _put_rows(words: np.ndarray, ids, free: np.ndarray,
+              spot_frac: np.ndarray) -> None:
+    """Write the rewritten rows into the last words of a packed input:
+    :func:`dirty_capacity` row ids (unused slots hold the row count), then
+    their (free, spot_frac) rows in float32."""
+    rows, d = free.shape
+    k_cap, k = dirty_capacity(rows), len(ids)
+    words[:k] = ids
+    words[k:k_cap] = rows
+    vals = words[k_cap:].view("<f4").reshape(k_cap, 2 * d)
     vals[:k, :d] = free[ids]
     vals[:k, d:] = spot_frac[ids]
-    return buf
+
+
+def _write_rows(free, spot_frac, words, at: int):
+    """The mirror with the rows that :func:`_put_rows` packed into
+    ``words[at:]`` (uint32) written in.  One row at a time, as many as were
+    sent: a scatter would have the TPU lay the mirror out row-major (4 ->
+    128 lanes) and copy it twice."""
+    rows, d = free.shape
+    k_cap = dirty_capacity(rows)
+    ids = words[at: at + k_cap].astype(jnp.int32)
+    vals = jax.lax.bitcast_convert_type(words[at + k_cap:], jnp.float32)
+    vals = vals.reshape(k_cap, 2 * d)
+
+    def write_row(i, mirror):
+        f, s = mirror
+        f = jax.lax.dynamic_update_slice(f, vals[i, None, :d], (ids[i], 0))
+        s = jax.lax.dynamic_update_slice(s, vals[i, None, d:], (ids[i], 0))
+        return f, s
+
+    return jax.lax.fori_loop(0, jnp.sum(ids < rows), write_row,
+                             (free, spot_frac))
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -438,7 +487,7 @@ def hlem_scores_tol_jax_resident(free, spot_frac, packed):
     (:func:`pack_pick`) carries into ``free`` and ``spot_frac`` in place,
     scores, and returns the new mirror and ``[scores..., tol]``."""
     rows, d = free.shape
-    head, k_cap, _ = _pick_layout(rows, d)
+    head, _, _ = _pick_layout(rows, d)
     # a byte a row and no arithmetic on it: unpacking bits here changes
     # how XLA fuses the scorer, and its float32 scores then differ by ulps
     # from :func:`hlem_scores_tol_jax`'s
@@ -446,25 +495,190 @@ def hlem_scores_tol_jax_resident(free, spot_frac, packed):
     words = jax.lax.bitcast_convert_type(packed[head:].reshape(-1, 4),
                                          jnp.uint32)
     alpha = jax.lax.bitcast_convert_type(words[0], jnp.float32)
-    ids = words[1: 1 + k_cap].astype(jnp.int32)
-    vals = jax.lax.bitcast_convert_type(words[1 + k_cap:], jnp.float32)
-    vals = vals.reshape(k_cap, 2 * d)
-
-    # one row at a time, as many as were sent: a scatter would have the
-    # TPU lay the mirror out row-major (4 -> 128 lanes) and copy it twice
-    def write_row(i, mirror):
-        f, s = mirror
-        f = jax.lax.dynamic_update_slice(f, vals[i, None, :d], (ids[i], 0))
-        s = jax.lax.dynamic_update_slice(s, vals[i, None, d:], (ids[i], 0))
-        return f, s
-
-    free, spot_frac = jax.lax.fori_loop(0, jnp.sum(ids < rows), write_row,
-                                        (free, spot_frac))
+    free, spot_frac = _write_rows(free, spot_frac, words, 1)
     scores, tol = _scores_and_tol(free, mask, spot_frac, alpha)
     # tol written into the slot after the scores: a concatenate lets XLA
     # fuse tol's arithmetic otherwise, and round it by an ulp
     out = jax.lax.dynamic_update_slice(jnp.pad(scores, (0, 1)), tol[None],
                                        (rows,))
+    return free, spot_frac, out
+
+
+def _run_step_words(d: int) -> int:
+    """float32 words of one run step's parameters in :func:`pack_run`:
+    alpha, a spot flag, then per dimension the feasibility threshold (the
+    demand less the pool's slack) and the demand."""
+    return 2 + 2 * d
+
+
+def _run_layout(rows: int, d: int, steps: int):
+    """(mask bytes a step, padded to whole words; words a step's parameters
+    take; total bytes) of :func:`pack_run`'s input for a storage of
+    ``rows`` x ``d`` and ``steps`` steps."""
+    head = -(-rows // 4) * 4
+    sw = _run_step_words(d)
+    return head, sw, steps * (head + 4 * sw) + 4 * (
+        3 + dirty_capacity(rows) * (1 + 2 * d))
+
+
+def run_out_width(rows: int, d: int, steps: int) -> int:
+    """Columns of a step's line in :func:`hlem_scores_tol_jax_run`'s
+    output: ``rows`` scores, tol, the pick, the primary flag, the number
+    of candidates, the bits of the rows each earlier step chose, the
+    chosen row as written (free, spot_frac), and tol's weights, spans and
+    magnitudes (a word each dimension)."""
+    return rows + 4 + steps + 5 * d
+
+
+def pack_run(masks: np.ndarray, params: np.ndarray, rc: float,
+             threshold: float, steps: int, ids, free: np.ndarray,
+             spot_frac: np.ndarray) -> np.ndarray:
+    """The one host array a run call sends, of a length fixed by the
+    storage's shape and ``steps``: a mask byte a row for each step (bit 0
+    feasibility, bit 1 the RsDiff primary list; ``masks`` is (L, n) with L
+    <= ``steps``, rows past n and steps past L zero), then little-endian
+    words: L, ``rc``, ``threshold``, each step's :func:`_run_step_words`
+    float32 parameters (``params``, (L, words)), and the rewritten rows as
+    :func:`pack_pick` carries them."""
+    rows, d = free.shape
+    head, sw, size = _run_layout(rows, d, steps)
+    n_steps, n = masks.shape
+    buf = np.zeros(size, dtype=np.uint8)
+    buf[: steps * head].reshape(steps, head)[:n_steps, :n] = masks
+    words = buf[steps * head:].view("<u4")
+    words[0] = n_steps
+    words[1:3] = np.array([rc, threshold], dtype="<f4").view("<u4")
+    par = words[3: 3 + steps * sw].view("<f4").reshape(steps, sw)
+    par[:n_steps] = params
+    _put_rows(words[3 + steps * sw:], ids, free, spot_frac)
+    return buf
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def hlem_scores_tol_jax_run(free, spot_frac, tot, packed):
+    """A run of consecutive placements scored on the device in one call:
+    the mirror of :func:`hlem_scores_tol_jax_resident` (donated, the rows
+    ``packed`` (:func:`pack_run`) carries written first), ``tot`` the
+    float32 denominators of ``spot_frac`` (``HostPool.scoring_totals``).
+    Returns the new mirror and a (steps, :func:`run_out_width`) array.
+
+    Step i starts from the host's masks for VM i at the call's state and
+    rechecks, in float32 on the mirror, each row an earlier step chose:
+    feasibility (the base bit, which holds the market admission and pins,
+    and the row's free capacity against the threshold) and RsDiff (Eq. 1,
+    the row's CPU use read as its total less its free CPU).  Rows nothing
+    chose keep their base bits: between two arrivals nothing else touches
+    the pool, and prices move only at ticks.  It takes the primary list if
+    it is non-empty, else feasibility, scores with :func:`_scores_and_tol`,
+    picks the first float32 maximum and writes the VM's demand into that
+    row (free less the demand; spot_frac plus demand / tot for a spot VM).
+    A step whose feasibility is empty picks -1 and writes nothing.  Its
+    line holds the scores and tol, the pick, the primary flag, the number
+    of candidates, for each earlier step j the bits (feasible + 2 x
+    primary) it used for j's row (0 where j chose none), its own row as
+    written, and the weights w, spans and magnitudes ``tol`` was made
+    from.  The rechecks of a step are one pass over (steps, rows) hits,
+    not a loop over the earlier steps: a loop's iterations cost the TPU
+    more than the scoring does.
+
+    Soundness.  The written rows are float32 arithmetic, not float32 of
+    the float64 result: a free value is off by up to the rounding of the
+    old value, of the demand and of the difference, about 1.5 units in
+    the last place where float32 of the host's value is off by half of
+    one.  ``tol``'s rounding term, 2^-20 x sum_d w_d mag_d / span_d, is
+    16 times what inputs rounded at 2^-24 of their column's largest
+    magnitude ``mag_d`` move a standardized score (Eq. 3), the premise it
+    holds on; a written row can break that premise.  So before it uses
+    step k the host (:class:`~repro.core.allocation.HlemVmp`) measures,
+    for every row an earlier step wrote that step k scores, the distance
+    e_d of the device's row from its float64 row, and certifies with tol
+    computed as if each column's magnitude were max(mag_d, 2^24 e_d):
+    ``tol + 2^-20 x sum_d w_d max(0, 2^24 e_d - mag_d) / span_d``, plus
+    ``2 |alpha| sum_d w_d e'_d`` for the spot loads' distances e'_d (Eq.
+    11 moves a score by at most |alpha| x its spot load's error, HS lying
+    in [0, 1]).  A float32-degenerate dimension (span <= 1e-12)
+    whose written rows lie off drops the rest of the run, as do bits the
+    float64 state does not give.  Decisions are then the single pick's:
+    the float64 oracle's argmax, certified or picked on the host."""
+    rows, d = free.shape
+    head, sw, size1 = _run_layout(rows, d, 1)
+    fixed = size1 - head - 4 * sw
+    steps = (packed.shape[0] - fixed) // (head + 4 * sw)
+    words = jax.lax.bitcast_convert_type(
+        packed[steps * head:].reshape(-1, 4), jnp.uint32)
+    n_steps = words[0].astype(jnp.int32)
+    rc = jax.lax.bitcast_convert_type(words[1], jnp.float32)
+    threshold = jax.lax.bitcast_convert_type(words[2], jnp.float32)
+    params = jax.lax.bitcast_convert_type(words[3: 3 + steps * sw],
+                                          jnp.float32).reshape(steps, sw)
+    free, spot_frac = _write_rows(free, spot_frac, words, 3 + steps * sw)
+    width = run_out_width(rows, d, steps)
+    hosts = jnp.arange(rows, dtype=jnp.int32)
+
+    def row_of(a, h):
+        return jax.lax.dynamic_slice(a, (h, 0), (1, d))[0]
+
+    def step(i, carry):
+        # picks: each step's row (-1: none yet, or none fitted); chosen,
+        # totals: that row as the latest step wrote it, and its tot row
+        free, spot_frac, out, picks, chosen, totals = carry
+        byte = jax.lax.dynamic_slice(packed, (i * head,), (rows,))
+        par = jax.lax.dynamic_slice(params, (i, 0), (1, sw))[0]
+        alpha, spot, need, dem = par[0], par[1], par[2: 2 + d], par[2 + d:]
+        base_f, base_p = (byte & 1) != 0, (byte & 2) != 0
+        # the rows earlier steps chose, rechecked all at once: (steps,
+        # rows) hits, no loop over the steps
+        hit = picks[:, None] == hosts[None, :]
+        util = (totals[:, 0] - chosen[:, 0]) / totals[:, 0]
+        f_bit = (jnp.any(hit & base_f[None, :], axis=1)
+                 & jnp.all(chosen >= need[None, :], axis=1))
+        p_bit = f_bit & (dem[0] / totals[:, 0] - util * rc > threshold)
+        again = jnp.any(hit, axis=0)
+        feas = jnp.where(again, jnp.any(hit & f_bit[:, None], axis=0),
+                         base_f)
+        prim = jnp.where(again, jnp.any(hit & p_bit[:, None], axis=0),
+                         base_p)
+        bits = f_bit.astype(jnp.float32) + 2.0 * p_bit.astype(jnp.float32)
+        any_f = jnp.any(feas)
+        use_p = jnp.any(prim)
+        mask = jnp.where(use_p, prim, feas)
+        scores, tol, w, span, mag = _scores_tol_terms(free, mask, spot_frac,
+                                                      alpha)
+        pick = jnp.where(any_f, jnp.argmax(scores).astype(jnp.int32), -1)
+        pc = jnp.maximum(pick, 0)
+        row, srow, t = row_of(free, pc), row_of(spot_frac, pc), row_of(tot,
+                                                                       pc)
+        row = jnp.where(any_f, row - dem, row)
+        srow = jnp.where(any_f & (spot > 0), srow + dem / t, srow)
+        free = jax.lax.dynamic_update_slice(free, row[None], (pc, 0))
+        spot_frac = jax.lax.dynamic_update_slice(spot_frac, srow[None],
+                                                 (pc, 0))
+        picks = jax.lax.dynamic_update_slice(picks, pick[None], (i,))
+        same = (picks == pick)[:, None]
+        chosen = jnp.where(same, row[None, :], chosen)
+        totals = jnp.where(same, t[None, :], totals)
+        # each piece written into its slots: a concatenate lets XLA fuse
+        # tol's arithmetic, and round it by an ulp
+        line = jnp.zeros(width, jnp.float32)
+        line = jax.lax.dynamic_update_slice(line, scores, (0,))
+        line = jax.lax.dynamic_update_slice(line, tol[None], (rows,))
+        line = jax.lax.dynamic_update_slice(
+            line, jnp.stack([pick.astype(jnp.float32),
+                             use_p.astype(jnp.float32),
+                             jnp.sum(mask.astype(jnp.float32))]),
+            (rows + 1,))
+        line = jax.lax.dynamic_update_slice(line, bits, (rows + 4,))
+        line = jax.lax.dynamic_update_slice(
+            line, jnp.concatenate([row, srow, w, span, mag]),
+            (rows + 4 + steps,))
+        out = jax.lax.dynamic_update_slice(out, line[None], (i, 0))
+        return free, spot_frac, out, picks, chosen, totals
+
+    free, spot_frac, out, *_ = jax.lax.fori_loop(
+        0, n_steps, step,
+        (free, spot_frac, jnp.zeros((steps, width), jnp.float32),
+         jnp.full(steps, -1, jnp.int32), jnp.zeros((steps, d), jnp.float32),
+         jnp.ones((steps, d), jnp.float32)))
     return free, spot_frac, out
 
 
@@ -474,10 +688,12 @@ class ResidentScorer:
     array and the rows changed since the previous pick, and reads one
     array back.  The caller tracks which rows changed; a new pool, grown
     storage or more changed rows than :func:`dirty_capacity` take
-    :meth:`upload` instead."""
+    :meth:`upload` instead.  A run call (:meth:`scores_tol_run`) also reads
+    ``tot``, the float32 denominators of ``spot_frac``, which change only
+    where a host's capacity does (:meth:`upload_tot`)."""
 
     def __init__(self):
-        self.free = self.spot_frac = None
+        self.free = self.spot_frac = self.tot = None
 
     @property
     def rows(self) -> int:
@@ -495,6 +711,20 @@ class ResidentScorer:
         program ends, not when the host asks)."""
         self.free, self.spot_frac, out = hlem_scores_tol_jax_resident(
             self.free, self.spot_frac, packed)
+        out.copy_to_host_async()
+        return out
+
+    def upload_tot(self, tot: np.ndarray) -> int:
+        """Replace ``tot`` by the pool's; returns the bytes sent."""
+        host = tot.astype(np.float32)
+        self.tot = jax.device_put(host)
+        return host.nbytes
+
+    def scores_tol_run(self, packed: np.ndarray) -> jax.Array:
+        """:func:`hlem_scores_tol_jax_run` on the mirror: the steps' lines
+        on the device, their copy to the host already requested."""
+        self.free, self.spot_frac, out = hlem_scores_tol_jax_run(
+            self.free, self.spot_frac, self.tot, packed)
         out.copy_to_host_async()
         return out
 

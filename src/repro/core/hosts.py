@@ -74,6 +74,9 @@ class HostPool:
         self.residents: List[Dict[int, Vm]] = [dict() for _ in range(n)]
         # -- incremental caches (epoch-stamped) ------------------------------
         self.epoch = 0
+        #: bumped whenever a host's capacity is (re)set, so a device copy of
+        #: :meth:`scoring_totals` knows when it went stale
+        self.static_epoch = 0
         #: total - used where active, 0 elsewhere; updated row-wise in place
         self._free = np.zeros((n, N_DIMS), dtype=np.float64)
         #: spot_used / max(total, 1e-9) per (host, dim)
@@ -183,6 +186,7 @@ class HostPool:
         """Recompute capacity-derived caches (host add / capacity update)."""
         np.maximum(self.total[hid], _EPS, out=self._tot_clamped[hid])
         self._rs_tot_cpu[hid] = max(float(self.total[hid, 0]), _EPS_RS)
+        self.static_epoch += 1
 
     def _refresh_row(self, hid: int, spot_changed: bool = True) -> None:
         """Recompute load-derived caches for one host (place/release path)."""
@@ -275,6 +279,12 @@ class HostPool:
         the storage grows (doubling)."""
         return self._free, self._spot_frac
 
+    def scoring_totals(self) -> np.ndarray:
+        """max(total, 1e-9) over every allocated row, the denominators of
+        ``spot_frac`` (rows past ``n`` hold 1e-9); it changes where
+        ``static_epoch`` moves or the storage grows."""
+        return self._tot_clamped
+
     def spot_frac_view(self) -> np.ndarray:
         """(n_hosts, 4) spot_used / total (cached)."""
         return self._spot_frac[: self.n]
@@ -358,10 +368,16 @@ class HostPool:
         comparison for the whole resubmission queue.  ``bids`` / ``pids``
         (per-row bid and pool pin) apply the market admission of
         :meth:`market_admit` row-wise."""
-        demands = np.asarray(demands, dtype=np.float64)
+        need = np.asarray(demands, dtype=np.float64) - _EPS
         n = self.n
-        ok = np.all(self._free[None, :n] >= demands[:, None] - _EPS, axis=2)
-        ok &= self.active[:n][None]
+        # a contiguous row per dimension: each (VM, dimension) compares n
+        # hosts in one loop, where a reduction over the last axis of
+        # (B, n, D) would run B x n loops of D
+        by_dim = self._free[:n].T.copy()
+        ok = by_dim[0] >= need[:, 0, None]
+        for k in range(1, by_dim.shape[0]):
+            ok &= by_dim[k] >= need[:, k, None]
+        ok &= self.active[:n]
         if self._market_on and bids is not None:
             finite = np.isfinite(bids)
             if finite.any():

@@ -25,6 +25,7 @@ batched path is regression-tested against.
 """
 from __future__ import annotations
 
+import collections
 import heapq
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
@@ -305,26 +306,64 @@ class MarketSimulator:
         span = tr.span
         inc = tr.counters.inc
         due = tr.counters_due
-        while heap and heap[0][0] <= limit:
-            ev = heappop(heap)[3]
-            t = ev.time
-            self.now = t
-            kind = ev.kind
-            inc("events/total")
-            inc(_KIND_COUNTER[kind])
-            # a handler that raises closes its open spans as aborted, so a
-            # truncated trace still exports as valid Chrome JSON
-            with span("event-loop", _DISPATCH_SPAN[kind], t):
-                self._dispatch(ev)
-            if due(t):
-                tr.snapshot(t, self._obs_gauges())
-            if strict:
-                self.pool.check_invariants(self.now)
+        policy = self.policy
+        run_k = policy.run_k
+        # the heap entries of the planned run's later arrivals, still in
+        # the heap: each must come out next, in its own turn
+        planned = collections.deque()
+        try:
+            while heap and heap[0][0] <= limit:
+                entry = heappop(heap)
+                ev = entry[3]
+                t = ev.time
+                self.now = t
+                kind = ev.kind
+                if run_k > 1:
+                    if planned and entry is planned[0]:
+                        planned.popleft()
+                    else:
+                        if planned:
+                            # an event between the run's arrivals
+                            planned.clear()
+                            policy.end_run()
+                        if kind is EventKind.VM_SUBMIT:
+                            self._look_ahead(ev, heap, limit, run_k, planned)
+                inc("events/total")
+                inc(_KIND_COUNTER[kind])
+                # a handler that raises closes its open spans as aborted,
+                # so a truncated trace still exports as valid Chrome JSON
+                with span("event-loop", _DISPATCH_SPAN[kind], t):
+                    self._dispatch(ev)
+                if due(t):
+                    tr.snapshot(t, self._obs_gauges())
+                if strict:
+                    self.pool.check_invariants(self.now)
+        finally:
+            # no run outlives the loop, also where a handler raised
+            if run_k > 1:
+                policy.end_run()
         self.now = min(limit, self.now) if limit != float("inf") else self.now
         if tr.enabled:
             # closing snapshot so the counter timeseries covers run end
             tr.snapshot(self.now, self._obs_gauges())
         return self.metrics
+
+    def _look_ahead(self, ev: Event, heap: list, limit: float, run_k: int,
+                    planned) -> None:
+        """Hand the policy the run of arrivals that starts with ``ev``: the
+        ``VM_SUBMIT`` events due next, up to ``limit``, at most ``run_k`` in
+        all.  They are read off the heap and put back, so the loop
+        dispatches them in their own turn, in key order, with anything
+        their handlers schedule before them (the run then ends)."""
+        while (len(planned) < run_k - 1 and heap and heap[0][0] <= limit
+               and heap[0][3].kind is EventKind.VM_SUBMIT):
+            planned.append(heapq.heappop(heap))
+        for entry in planned:
+            heapq.heappush(heap, entry)
+        if planned:
+            vms = self.vms
+            self.policy.plan_run([vms[ev.payload]]
+                                 + [vms[e[3].payload] for e in planned])
 
     def _obs_gauges(self) -> Dict[str, float]:
         """Point-in-time gauges merged into each counter snapshot."""

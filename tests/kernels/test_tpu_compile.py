@@ -104,3 +104,20 @@ def test_resident_pick_compiles_for_v5e_and_updates_in_place(one_chip, rows):
                                                  sharding=one_chip))
     compiled = hlem_scores_tol_jax_resident.lower(*args).compile()
     assert "input_output_alias" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [256, 16_384])
+def test_run_pick_compiles_for_v5e_and_updates_in_place(one_chip, rows):
+    """A run of ``PICK_RUN_K`` placements in one call, at both storages:
+    the mirror is donated and written in place; ``tot`` is only read."""
+    from repro.core.hlem import (PICK_RUN_K, hlem_scores_tol_jax_run,
+                                 pack_run)
+    packed = pack_run(np.zeros((1, rows), dtype=np.uint8), np.zeros((1, 10)),
+                      0.95, 0.0, PICK_RUN_K, [], np.zeros((rows, 4)),
+                      np.zeros((rows, 4)))
+    mirror = jax.ShapeDtypeStruct((rows, 4), jnp.float32, sharding=one_chip)
+    args = (mirror, mirror, mirror,
+            jax.ShapeDtypeStruct(packed.shape, packed.dtype,
+                                 sharding=one_chip))
+    compiled = hlem_scores_tol_jax_run.lower(*args).compile()
+    assert "input_output_alias" in compiled.as_text()

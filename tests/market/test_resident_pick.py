@@ -10,6 +10,7 @@ import pytest
 from repro.api import (MigrationSpec, ObsSpec, PolicySpec, RunSpec,
                        ScenarioSpec, build)
 from repro.api.build import collect_row
+from repro.core import hlem
 from repro.core.allocation import HlemVmp, HlemVmpAdjusted
 from repro.core.hlem import (ResidentScorer, dirty_capacity,
                              hlem_scores_tol_jax,
@@ -203,7 +204,10 @@ def _trace(backend):
 
 
 @pytest.mark.parametrize("make", [_market, _trace], ids=["market", "trace"])
-def test_runs_decide_as_the_numpy_backend(make, checked):
+def test_runs_decide_as_the_numpy_backend(make, checked, monkeypatch):
+    # every pick a single one, through the resident program (runs of
+    # arrivals: tests/market/test_run_pick.py)
+    monkeypatch.setattr(hlem, "PICK_RUN_K", 1)
     hlem_scores_tol_jax_resident.clear_cache()
     runs = {}
     for backend in ("numpy", "jax"):

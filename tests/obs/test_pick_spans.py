@@ -15,7 +15,8 @@ import pytest
 from repro.api import (MigrationSpec, ObsSpec, PolicySpec, RunSpec,
                        ScenarioSpec, build)
 from repro.api.build import collect_row
-from repro.core.hlem import device_arg_bytes, dirty_capacity, pack_pick
+from repro.core.hlem import (PICK_RUN_K, device_arg_bytes, dirty_capacity,
+                             pack_pick, pack_run)
 from repro.obs import Tracer, first_divergence
 
 SEED = 5
@@ -67,28 +68,42 @@ def test_pick_spans_count_the_policys_picks_over_a_window(runs):
     sim, _, ((picks0, falls0), prof0, counters0) = runs[True]
     picks, falls = _picks(sim)
     prof = sim.obs.profile()
-    assert picks - picks0 > 0 and falls - falls0 > 0
-    assert _count(prof, "pick/call", prof0) == picks - picks0
-    assert _count(prof, "pick/readback", prof0) == picks - picks0
+    c = sim.obs.counters.values
+
+    def sent(name):
+        return c.get(name, 0) - counters0.get(name, 0)
+
+    calls, runs = sent("pick/device_calls"), sent("pick/run_calls")
+    assert picks - picks0 > 0 and falls - falls0 > 0 and runs > 0
+    assert _count(prof, "pick/call", prof0) == calls
+    assert _count(prof, "pick/readback", prof0) == calls
     assert _count(prof, "pick/host-exact", prof0) == falls - falls0
+    # a single call is one pick; a run call one for each step the host
+    # used that placed
+    assert calls - runs <= picks - picks0 <= (calls - runs
+                                               + sent("pick/run_taken"))
     # every call sends one packed array, of a length fixed by the storage
-    # (a mask byte a row, then alpha, K row ids and their 2 x D float32
-    # values in words), and an upload sends the whole storage in float32
+    # (a single pick's: a mask byte a row, then alpha, K row ids and their
+    # 2 x D float32 values in words; a run's: PICK_RUN_K mask bytes a row,
+    # three words and the steps' parameters, the same rows), and an upload
+    # sends the whole storage in float32 (a run's totals: half of that)
     free, spot_frac = sim.pool.storage_views()
     rows, d = free.shape
     k_cap = dirty_capacity(rows)
     per_call = pack_pick(np.zeros(rows, dtype=bool), 0.0, [], free,
                          spot_frac).nbytes
     assert per_call == -(-rows // 4) * 4 + 4 * (1 + k_cap * (1 + 2 * d))
-    per_upload = rows * d * 4 * 2
-    c = sim.obs.counters.values
-
-    def sent(name):
-        return c[name] - counters0.get(name, 0)
-
-    assert sent("pick/h2d_bytes") == ((picks - picks0) * per_call
+    per_run = pack_run(np.zeros((1, rows), dtype=np.uint8),
+                       np.zeros((1, 2 + 2 * d)), 0.95, 0.0, PICK_RUN_K, [],
+                       free, spot_frac).nbytes
+    assert per_run == PICK_RUN_K * (-(-rows // 4) * 4 + 4 * (2 + 2 * d)) \
+        + 4 * (3 + k_cap * (1 + 2 * d))
+    per_tot = rows * d * 4
+    assert sent("pick/h2d_bytes") == ((calls - runs) * per_call
+                                      + runs * per_run
                                       + sent("pick/mirror_uploads")
-                                      * per_upload)
+                                      * 2 * per_tot
+                                      + sent("pick/tot_uploads") * per_tot)
     # a float64 pick after a fallback scores the candidates, at least the
     # two hosts float32 could not order
     hosts = sim.pool.free().shape[0]
@@ -96,7 +111,7 @@ def test_pick_spans_count_the_policys_picks_over_a_window(runs):
             <= (falls - falls0) * hosts)
     # the run's first pick uploads; after it, rewritten rows ride along
     assert c["pick/mirror_uploads"] >= 1
-    assert 0 < sent("pick/dirty_rows") <= (picks - picks0) * k_cap
+    assert 0 < sent("pick/dirty_rows") <= calls * k_cap
 
 
 def test_h2d_bytes_leave_out_arguments_already_on_the_device():

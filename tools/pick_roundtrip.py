@@ -1,9 +1,10 @@
 #!/usr/bin/env python3
-"""Time the device pick's round trip three ways, on one TPU chip.
+"""Time the device pick's round trip, on one TPU chip.
 
 Run from the repository root, on a machine with a TPU:
 
     PYTHONPATH=src python3 tools/pick_roundtrip.py [--rows R ...] [--iters N]
+        [--way NAME ...] [--steps K ...]
 
 For each storage size (rows of 4 float64 columns, as ``HostPool`` keeps
 them; a quarter of the rows past the hosts, as at 12,600 hosts in 16,384
@@ -17,18 +18,23 @@ rows), one pick is made ``--iters`` times each way:
 * ``resident``: ``ResidentScorer``, the mirror kept on the device, the
   packed input carrying the mask, alpha and two rewritten rows a pick; one
   array fetched.
+* ``run``: ``ResidentScorer.scores_tol_run``, a run of K placements in one
+  call (``hlem_scores_tol_jax_run``), for each K of ``--steps``: the
+  packed input carrying K mask bytes a row, K steps' parameters and two
+  rewritten rows; one (K, rows + ...) array fetched.  Its lines carry
+  ``steps`` and the call's microseconds a step.
 
 Per pick it reads the host clock around the call (conversion, transfer,
 enqueue, and for ``resident`` the request of the copy back: ``call``), the
 wait for the device (``block_until_ready``: ``wait``) and the copy back
 (``fetch``); ``readback`` is wait plus fetch, as the program's
-``pick/readback`` span counts it.  One JSON line per (rows, way) gives the
-median and quartiles in microseconds and the bytes a pick sends; a last
-line per size checks the resident scores and tolerance against
-``hlem_scores_tol_jax`` bit for bit, and the mirror against the storage in
-float32.  Without a TPU the script exits 3; ``--rehearse`` runs it on
-whatever JAX finds, a few iterations, to check the script itself (no
-measurement).
+``pick/readback`` span counts it.  One JSON line per (rows, way, steps)
+gives the median and quartiles in microseconds and the bytes a call sends;
+lines per size check the resident scores and tolerance against
+``hlem_scores_tol_jax`` bit for bit, the mirror against the storage in
+float32, and a run's first step's pick against the resident pick.
+Without a TPU the script exits 3; ``--rehearse`` runs it on whatever JAX
+finds, a few iterations, to check the script itself (no measurement).
 """
 from __future__ import annotations
 
@@ -58,6 +64,51 @@ def _storage(rows: int, seed: int):
 def _quartiles(xs):
     q1, q2, q3 = statistics.quantiles(xs, n=4)
     return {"median": q2, "q1": q1, "q3": q3}
+
+
+def _run_way(steps: int):
+    """The ``run`` way at ``steps`` placements a call: every step has the
+    mask, odd steps also the primary list of its even rows, demands of one
+    CPU and 1,536 MB.  The rows a call wrote go back with the next call,
+    as ``HlemVmp`` sends them."""
+    from repro.core.hlem import ResidentScorer, dirty_capacity, pack_run
+
+    def run(free, spot, mask, alpha, state):
+        if "mirror" not in state:
+            rows, d = free.shape
+            state["mirror"] = ResidentScorer()
+            state["mirror"].upload(free, spot)
+            state["mirror"].upload_tot(np.maximum(free, 1.0) * 2.0)
+            state["k"] = 0
+            prim = mask & (np.arange(rows) % 2 == 0)
+            state["masks"] = np.where(np.arange(steps)[:, None] % 2,
+                                      mask.view(np.uint8)
+                                      | (prim.view(np.uint8) << 1),
+                                      mask.view(np.uint8))
+            state["wrote"] = []
+            demand = np.array([1.0, 1_536.0, 10.0, 1_000.0][:d])
+            par = np.concatenate([[alpha, 1.0], demand - 1e-9, demand])
+            state["params"] = np.tile(par, (steps, 1))
+        k = state["k"] = (state["k"] + 2) % (free.shape[0] - 2)
+        free[[k, k + 1]] *= 0.999
+        ids = sorted({k, k + 1, *state["wrote"]})
+        t0 = time.perf_counter()
+        sent = 0
+        if len(ids) > dirty_capacity(free.shape[0]):
+            sent, ids = state["mirror"].upload(free, spot), []
+        packed = pack_run(state["masks"], state["params"], 0.95, 0.0,
+                          steps, ids, free, spot)
+        out = state["mirror"].scores_tol_run(packed)
+        t1 = time.perf_counter()
+        out.block_until_ready()
+        t2 = time.perf_counter()
+        state["out"] = np.asarray(out)
+        t3 = time.perf_counter()
+        picks = state["out"][:, free.shape[0] + 1]
+        state["wrote"] = [int(p) for p in picks[picks >= 0]]
+        return t1 - t0, t2 - t1, t3 - t2, sent + packed.nbytes
+
+    return run
 
 
 def _ways():
@@ -133,6 +184,10 @@ def main(argv=None) -> int:
     ap.add_argument("--rows", type=int, nargs="+", default=[256, 16_384])
     ap.add_argument("--iters", type=int, default=500)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--way", nargs="+",
+                    default=["args4", "packed1", "resident", "run"])
+    ap.add_argument("--steps", type=int, nargs="+",
+                    default=[1, 2, 4, 8, 16, 32])
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
 
@@ -148,7 +203,12 @@ def main(argv=None) -> int:
     from repro.core.hlem import hlem_scores_tol_jax
 
     for rows in args.rows:
-        for name, way in _ways().items():
+        ways = [(name, None, way) for name, way in _ways().items()
+                if name in args.way]
+        if "run" in args.way:
+            ways += [("run", k, _run_way(k)) for k in args.steps]
+        picks = {}
+        for name, steps, way in ways:
             free, spot, mask = _storage(rows, args.seed)
             state = {}
             for _ in range(20):
@@ -167,8 +227,14 @@ def main(argv=None) -> int:
                     "fetch_us": _quartiles(fetches),
                     "readback_us": _quartiles(
                         [w + f for w, f in zip(waits, fetches)])}
+            if steps is not None:
+                total = statistics.median(
+                    [c + w + f for c, w, f in zip(calls, waits, fetches)])
+                line.update(steps=steps, round_trip_us_per_step=total / steps)
+                picks[steps] = int(state["out"][0, rows + 1])
             print(json.dumps(line), flush=True)
             if name == "resident":
+                picks["resident"] = int(np.argmax(state["out"][:-1]))
                 scores, tol = hlem_scores_tol_jax(free, mask, spot,
                                                   np.float32(-0.5))
                 want = np.append(np.asarray(scores), np.float32(tol))
@@ -183,6 +249,12 @@ def main(argv=None) -> int:
                                   bool(same), "mirror_equals_storage":
                                   bool(mirror), "device": device}),
                       flush=True)
+        if picks:
+            # each way's last call saw the same storage: a run's first
+            # step (the mask alone) picks as the resident program
+            print(json.dumps({"rows": rows, "first_step_picks": {
+                str(k): v for k, v in picks.items()}, "device": device}),
+                  flush=True)
     return 0
 
 
